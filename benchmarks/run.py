@@ -633,6 +633,9 @@ def bench_ckpt(out_path: str = "BENCH_ckpt.json"):
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # 8 virtual CPU devices; pinned to the CPU so the child never competes
+    # with this process for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root,
@@ -792,6 +795,9 @@ def bench_offload(out_path: str = "BENCH_offload.json"):
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    # 8 virtual CPU devices; pinned to the CPU so the child never competes
+    # with this process for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(root, "src"), root,

@@ -57,6 +57,20 @@ class CostConstants:
 
 V5E = CostConstants()
 
+#: Published bf16 peak FLOP/s of one chip, keyed by
+#: ``jax.Device.device_kind`` (Google Cloud documentation, "TPU v5e":
+#: 197 TFLOP/s bf16).  Utilization figures divide by this table only.
+PEAK_BF16_FLOPS = {"TPU v5 lite": V5E.peak}
+
+
+def peak_flops(device_kind: str) -> float:
+    """bf16 peak of one chip of ``device_kind``; a kind missing from
+    ``PEAK_BF16_FLOPS`` is an error, not a default."""
+    if device_kind not in PEAK_BF16_FLOPS:
+        raise KeyError(f"no published bf16 peak for device kind "
+                       f"{device_kind!r}; add it to PEAK_BF16_FLOPS")
+    return PEAK_BF16_FLOPS[device_kind]
+
 # Module-level aliases — single source of truth for every consumer that
 # previously duplicated these numbers (analysis/roofline.py and the
 # now-deprecated benchmarks/analytic.py shim).

@@ -49,7 +49,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core.runtime import shard_map_compat as _shard_map
 from repro.core.topology import (AXIS_HP, AXIS_INNER, AXIS_OUTER, BATCH_AXES,
                                  SEQ_AXES)
 from repro.core.zigzag import from_zigzag, to_zigzag
@@ -362,13 +361,15 @@ def attention_2d(q, k, v, *, mesh, cfg: Attn2DConfig, doc_start=None):
     """
     spec = P(BATCH_AXES, SEQ_AXES, None, None)
     if doc_start is None:
-        f = _shard_map(functools.partial(attention_2d_local, cfg=cfg),
-                       mesh, (spec, spec, spec), spec)
+        f = jax.shard_map(functools.partial(attention_2d_local, cfg=cfg),
+                          mesh=mesh, in_specs=(spec, spec, spec),
+                          out_specs=spec, check_vma=False)
         return f(q, k, v)
     spec_d = P(BATCH_AXES, SEQ_AXES)
-    f = _shard_map(
+    f = jax.shard_map(
         lambda q, k, v, d: attention_2d_local(q, k, v, cfg, doc_start=d),
-        mesh, (spec, spec, spec, spec_d), spec)
+        mesh=mesh, in_specs=(spec, spec, spec, spec_d), out_specs=spec,
+        check_vma=False)
     return f(q, k, v, jnp.asarray(doc_start, jnp.int32))
 
 
@@ -472,25 +473,28 @@ def _chunk_pair_fns(mesh, cfg: Attn2DConfig, has_doc: bool):
     spec_d = P(BATCH_AXES, SEQ_AXES)
     sc = P()
     if has_doc:
-        fwd = _shard_map(
+        fwd = jax.shard_map(
             lambda q, k, v, d, qb, kb:
                 _chunk_pair_fwd_local(q, k, v, d, qb, kb, cfg),
-            mesh, (spec, spec, spec, spec_d, sc, sc), (spec, spec_l))
-        bwd = _shard_map(
+            mesh=mesh, in_specs=(spec, spec, spec, spec_d, sc, sc),
+            out_specs=(spec, spec_l), check_vma=False)
+        bwd = jax.shard_map(
             lambda q, k, v, o, l, g, d, qb, kb:
                 _chunk_pair_bwd_local(q, k, v, o, l, g, d, qb, kb, cfg),
-            mesh, (spec, spec, spec, spec, spec_l, spec, spec_d, sc, sc),
-            (spec, spec, spec))
+            mesh=mesh,
+            in_specs=(spec, spec, spec, spec, spec_l, spec, spec_d, sc, sc),
+            out_specs=(spec, spec, spec), check_vma=False)
     else:
-        fwd = _shard_map(
+        fwd = jax.shard_map(
             lambda q, k, v, qb, kb:
                 _chunk_pair_fwd_local(q, k, v, None, qb, kb, cfg),
-            mesh, (spec, spec, spec, sc, sc), (spec, spec_l))
-        bwd = _shard_map(
+            mesh=mesh, in_specs=(spec, spec, spec, sc, sc),
+            out_specs=(spec, spec_l), check_vma=False)
+        bwd = jax.shard_map(
             lambda q, k, v, o, l, g, qb, kb:
                 _chunk_pair_bwd_local(q, k, v, o, l, g, None, qb, kb, cfg),
-            mesh, (spec, spec, spec, spec, spec_l, spec, sc, sc),
-            (spec, spec, spec))
+            mesh=mesh, in_specs=(spec, spec, spec, spec, spec_l, spec, sc, sc),
+            out_specs=(spec, spec, spec), check_vma=False)
     return jax.jit(fwd), jax.jit(bwd)
 
 

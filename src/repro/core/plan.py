@@ -592,7 +592,8 @@ def build_plan(cfg, pc: ParallelConfig | None = None, opt=None, *,
 
     * ``devices`` / ``base_mesh`` — flat device list (tests, single-host)
       or a production ``(pod, data, model)`` mesh to refine.
-    * ``impl`` — attention impl; ``None`` auto-selects by backend.
+    * ``impl`` — attention impl; ``None`` picks the compiled Pallas
+      kernels on TPU and the dense jnp reference elsewhere.
     * ``remat`` — ``None`` keeps ``cfg.remat``; ``"auto"`` decides from
       the activation memory model (needs ``seq_len``+``global_batch``);
       an explicit policy overrides.
@@ -642,7 +643,7 @@ def build_plan(cfg, pc: ParallelConfig | None = None, opt=None, *,
     mesh = refine_mesh(base_mesh, pc) if base_mesh is not None \
         else make_mesh(pc, devices=devices)
     if impl is None:
-        impl = "auto" if jax.default_backend() == "tpu" else "ref"
+        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
 
     policy, zero_mode, groups, mem = plan_memory(
         cfg, pc, grad_accum=grad_accum, remat=remat, zero=zero,

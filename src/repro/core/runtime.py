@@ -1,5 +1,4 @@
-"""Runtime context threaded through model code: mesh + parallel layout,
-plus the version-portable ``shard_map`` entry point every module shares."""
+"""Runtime context threaded through model code: mesh + parallel layout."""
 from __future__ import annotations
 
 import dataclasses
@@ -8,42 +7,6 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.topology import BATCH_AXES, SEQ_AXES, ParallelConfig
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map`` (whose replication check is spelled
-    ``check_vma``); older versions either lack the top-level binding
-    entirely (``AttributeError``) or spell the flag ``check_rep`` — fall
-    through to ``jax.experimental.shard_map`` in both cases.
-
-    NOTE: the legacy module gives grad residuals worst-case dim-0
-    shardings, which rejects 0-d residuals (its scalar promotion misses
-    some) — shard-mapped code should carry (1,)-shaped accumulators
-    instead of scalars (see ``models/model.py::chunked_xent``).
-    """
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
-def axis_size_compat(axis) -> "jax.Array | int":
-    """``lax.axis_size`` across jax versions (older jax lacks it; the
-    psum of a constant 1 is the portable spelling)."""
-    from jax import lax
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,6 +23,5 @@ class Runtime:
         return P(self.batch_axes, SEQ_AXES, *trailing)
 
     def constrain(self, x, *trailing):
-        import jax
         return jax.lax.with_sharding_constraint(
             x, jax.sharding.NamedSharding(self.mesh, self.act_spec(*trailing)))

@@ -21,14 +21,21 @@ TPU-native adaptation of FlashAttention-2 for the LoongTrain reproduction:
   group-summed gradients in VMEM scratch, so replicated KV is never
   materialized anywhere.
 * **Packed documents** (``FlashParams.packed``): a per-q-row int32
-  doc-start table arrives as one more blocked ``(1, block_q)`` VMEM
+  doc-start table arrives as one more blocked ``(1, block_q, 1)`` VMEM
   operand (shared by all folded heads of a sequence); keys below a row's
   document start are masked, and K blocks entirely below a q block's
   first-row doc start are *skipped* at grid level (``doc_skip``).  The
   full contract is written down in docs/KERNELS.md.
 
+* Per-row vectors (``lse``, ``dsum``, the doc table, the running max and
+  sum) carry a trailing singleton axis, ``(rows, 1)``: the TPU lowering
+  takes a block whose last two dimensions are multiples of (8, 128) or
+  equal to the array's, so ``(block_q, 1)`` is legal at any batch size
+  where a ``(1, block_q)`` slice of a 2-D ``(B·H, L)`` array is not.
+
 Validated on CPU with ``interpret=True`` against ``ref.py`` (see
-``tests/test_kernels.py``).  On real TPUs set ``interpret=False``.
+``tests/test_kernels.py``); ``tests/test_tpu_compile.py`` compiles the
+kernels for a described v5e chip.
 """
 from __future__ import annotations
 
@@ -44,10 +51,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.ref import _logical_pos
 
 NEG_INF = -1e30
-
-# jax >= 0.5 renamed TPUCompilerParams -> CompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 
 class FlashParams(NamedTuple):
@@ -115,7 +118,7 @@ def _run_predicate(q_start, k_start, band_ref, p: FlashParams,
     if p.packed and p.doc_skip:
         run = jnp.logical_and(
             run,
-            _k_log(k_start + p.block_k - 1, band_ref, p) >= doc_ref[0, 0])
+            _k_log(k_start + p.block_k - 1, band_ref, p) >= doc_ref[0, 0, 0])
     return run
 
 
@@ -132,7 +135,7 @@ def _tile_mask(q_start, k_start, band_ref, p: FlashParams, doc_ref=None):
         if p.causal:
             mask &= k_log <= q_log
         if p.packed:
-            mask &= k_log >= doc_ref[0][:, None]
+            mask &= k_log >= doc_ref[0]
         if p.window is not None:
             mask &= k_log >= q_log - (p.window - 1)
     return mask
@@ -173,18 +176,19 @@ def _fwd_kernel(band_ref, *refs, p: FlashParams, nk: int):
         mask = _tile_mask(q_start, k_start, band_ref, p, doc_ref)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_cur = jnp.max(s, axis=1)
+        m_prev = m_ref[...]                          # (bq, 1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         # fully-masked-so-far rows: keep shift at 0 to avoid exp(inf) traps
         shift = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-        pmat = jnp.exp(s - shift[:, None])
+        pmat = jnp.exp(s - shift)
         pmat = jnp.where(mask, pmat, 0.0)
         alpha = jnp.exp(jnp.where(m_prev <= NEG_INF / 2, NEG_INF,
                                   m_prev - shift))
         alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, alpha)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(pmat, axis=1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(pmat, axis=1,
+                                                  keepdims=True)
+        acc_ref[...] = (acc_ref[...] * alpha
                         + jax.lax.dot_general(
                             pmat, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -194,7 +198,7 @@ def _fwd_kernel(band_ref, *refs, p: FlashParams, nk: int):
     def _finalize():
         l = l_ref[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
         m = m_ref[...]
         shift = jnp.where(m <= NEG_INF / 2, 0.0, m)
         lse_ref[0] = jnp.where(l == 0.0, NEG_INF, shift + jnp.log(l_safe))
@@ -206,10 +210,10 @@ def _fwd(q, k, v, p: FlashParams, band=None, doc=None):
     GQA is handled in the K/V index maps (kv row = q row // group), so the
     replicated KV is never materialized.  ``band``: optional int32 (5,)
     scalar-prefetch vector (see module docstring); defaults to the static
-    bottom-right band.  ``doc``: optional (B, Lq) int32 per-row doc-start
-    table (``p.packed`` must be set) — blocked over q, shared across the
-    folded heads of each sequence.  Returns out (BH, Lq, D),
-    lse (BH, Lq) fp32.
+    bottom-right band.  ``doc``: optional (B, Lq, 1) int32 per-row
+    doc-start table (``p.packed`` must be set) — blocked over q, shared
+    across the folded heads of each sequence.  Returns out (BH, Lq, D),
+    lse (BH, Lq, 1) fp32.
     """
     bh, lq, d = q.shape
     bhkv, lk, _ = k.shape
@@ -233,7 +237,7 @@ def _fwd(q, k, v, p: FlashParams, band=None, doc=None):
     if p.packed:
         q_mult = bh // doc.shape[0]
         in_specs.append(pl.BlockSpec(
-            (1, p.block_q), lambda b, i, j, s: (b // q_mult, i)))
+            (1, p.block_q, 1), lambda b, i, j, s: (b // q_mult, i, 0)))
         operands = (q, k, v, doc)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -241,12 +245,12 @@ def _fwd(q, k, v, p: FlashParams, band=None, doc=None):
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, p.block_q, d), lambda b, i, j, s: (b, i, 0)),
-            pl.BlockSpec((1, p.block_q), lambda b, i, j, s: (b, i)),
+            pl.BlockSpec((1, p.block_q, 1), lambda b, i, j, s: (b, i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((p.block_q, d), jnp.float32),
-            pltpu.VMEM((p.block_q,), jnp.float32),
-            pltpu.VMEM((p.block_q,), jnp.float32),
+            pltpu.VMEM((p.block_q, 1), jnp.float32),
+            pltpu.VMEM((p.block_q, 1), jnp.float32),
         ],
     )
     out, lse = pl.pallas_call(
@@ -254,9 +258,9 @@ def _fwd(q, k, v, p: FlashParams, band=None, doc=None):
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, lq), jnp.float32),
+            jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=p.interpret,
     )(band, *operands)
@@ -309,16 +313,16 @@ def _dq_kernel(band_ref, *refs, p: FlashParams, nk: int):
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
+        lse = lse_ref[0]                             # (bq, 1)
         dsum = dsum_ref[0]
 
         s, mask, s_raw = _recompute_p(q, k, q_start, k_start, band_ref, p,
                                       doc_ref)
         shift = jnp.where(lse <= NEG_INF / 2, 0.0, lse)
-        pmat = jnp.where(mask, jnp.exp(s - shift[:, None]), 0.0)
+        pmat = jnp.where(mask, jnp.exp(s - shift), 0.0)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = _ds_from_dp(dp - dsum[:, None], pmat, s, s_raw, p)
+        ds = _ds_from_dp(dp - dsum, pmat, s, s_raw, p)
         dq_acc[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -356,19 +360,19 @@ def _dkv_kernel(band_ref, *refs, p: FlashParams, nq: int, group: int):
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
+        lse = lse_ref[0]                             # (bq, 1)
         dsum = dsum_ref[0]
 
         s, mask, s_raw = _recompute_p(q, k, q_start, k_start, band_ref, p,
                                       doc_ref)
         shift = jnp.where(lse <= NEG_INF / 2, 0.0, lse)
-        pmat = jnp.where(mask, jnp.exp(s - shift[:, None]), 0.0)
+        pmat = jnp.where(mask, jnp.exp(s - shift), 0.0)
         dv_acc[...] += jax.lax.dot_general(
             pmat, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = _ds_from_dp(dp - dsum[:, None], pmat, s, s_raw, p)
+        ds = _ds_from_dp(dp - dsum, pmat, s, s_raw, p)
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -392,7 +396,7 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
     if band is None:
         band = _default_band(p)
     dsum = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                   axis=-1)  # (BH, Lq)
+                   axis=-1, keepdims=True)  # (BH, Lq, 1)
 
     dq_in_specs = [
         pl.BlockSpec((1, p.block_q, d), lambda b, i, j, s: (b, i, 0)),
@@ -401,14 +405,14 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
         pl.BlockSpec((1, p.block_k, d),
                      lambda b, i, j, s: (b // group, j, 0)),
         pl.BlockSpec((1, p.block_q, d), lambda b, i, j, s: (b, i, 0)),
-        pl.BlockSpec((1, p.block_q), lambda b, i, j, s: (b, i)),
-        pl.BlockSpec((1, p.block_q), lambda b, i, j, s: (b, i)),
+        pl.BlockSpec((1, p.block_q, 1), lambda b, i, j, s: (b, i, 0)),
+        pl.BlockSpec((1, p.block_q, 1), lambda b, i, j, s: (b, i, 0)),
     ]
     operands = (q, k, v, do, lse, dsum)
     if p.packed:
         q_mult = bh // doc.shape[0]
         dq_in_specs.append(pl.BlockSpec(
-            (1, p.block_q), lambda b, i, j, s: (b // q_mult, i)))
+            (1, p.block_q, 1), lambda b, i, j, s: (b // q_mult, i, 0)))
         operands = operands + (doc,)
     dq_grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -422,7 +426,7 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
         functools.partial(_dq_kernel, p=p, nk=nk),
         grid_spec=dq_grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=p.interpret,
     )(band, *operands)
@@ -438,16 +442,17 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
         pl.BlockSpec((1, p.block_q, d),
                      lambda b, j, g, s: (b * group + g // nq,
                                          g % nq, 0)),
-        pl.BlockSpec((1, p.block_q),
-                     lambda b, j, g, s: (b * group + g // nq, g % nq)),
-        pl.BlockSpec((1, p.block_q),
-                     lambda b, j, g, s: (b * group + g // nq, g % nq)),
+        pl.BlockSpec((1, p.block_q, 1),
+                     lambda b, j, g, s: (b * group + g // nq, g % nq, 0)),
+        pl.BlockSpec((1, p.block_q, 1),
+                     lambda b, j, g, s: (b * group + g // nq, g % nq, 0)),
     ]
     if p.packed:
         q_mult = bh // doc.shape[0]
         dkv_in_specs.append(pl.BlockSpec(
-            (1, p.block_q),
-            lambda b, j, g, s: ((b * group + g // nq) // q_mult, g % nq)))
+            (1, p.block_q, 1),
+            lambda b, j, g, s: ((b * group + g // nq) // q_mult,
+                                g % nq, 0)))
     dkv_grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bhkv, nk, group * nq),
@@ -468,7 +473,7 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
             jax.ShapeDtypeStruct((bhkv, lk, d), k.dtype),
             jax.ShapeDtypeStruct((bhkv, lk, d), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=p.interpret,
     )(band, *operands)
@@ -483,11 +488,6 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
 def _flash_folded(q, k, v, p: FlashParams):
     out, _ = _fwd(q, k, v, p)
     return out
-
-
-def _flash_folded_with_lse(q, k, v, p: FlashParams):
-    """Non-differentiable variant that also returns lse (for ring combine)."""
-    return _fwd(q, k, v, p)
 
 
 def _flash_fwd_rule(q, k, v, p: FlashParams):
@@ -507,7 +507,7 @@ _flash_folded.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _flash_folded_doc(q, k, v, doc, p: FlashParams):
-    """Packed-document variant: ``doc`` is the (B, Lq_pad) int32 per-row
+    """Packed-document variant: ``doc`` is the (B, Lq_pad, 1) int32 per-row
     doc-start table (integer data — its cotangent is float0)."""
     out, _ = _fwd(q, k, v, p, doc=doc)
     return out

@@ -107,15 +107,16 @@ def _make_params(q, k, *, causal, window, softcap, scale, kv_valid_len,
 
 
 def _pad_doc(q_doc_start, lq: int, block_q: int):
-    """(B, Lq) int32 doc-start table, q rows padded with ``DOC_PAD`` (the
-    padded rows attend nothing; their outputs are dropped)."""
+    """(B, Lq) int32 doc-start table -> the kernels' (B, Lq_pad, 1), q rows
+    padded with ``DOC_PAD`` (the padded rows attend nothing; their outputs
+    are dropped)."""
     doc = jnp.asarray(q_doc_start, jnp.int32)
     assert doc.ndim == 2 and doc.shape[1] == lq, (doc.shape, lq)
     lq_pad = _round_up(lq, block_q)
     if lq_pad != lq:
         doc = jnp.pad(doc, ((0, 0), (0, lq_pad - lq)),
                       constant_values=DOC_PAD)
-    return doc
+    return doc[:, :, None]
 
 
 def _band_scalars(band, mask_offset, lq: int, lk: int, kv_valid_len,
@@ -252,7 +253,7 @@ def flash_fwd_chunk(q, k, v, *, causal: bool = False,
     doc = None if q_doc_start is None else _pad_doc(q_doc_start, lq, bq)
     out, lse = _fwd(qf, kf, vf, p, band=scalars, doc=doc)
     out = _unfold(out, b, hq, lq, d)
-    lse = lse[:, :lq].reshape(b, hq, lq)
+    lse = lse[:, :lq, 0].reshape(b, hq, lq)
     return out, lse
 
 
@@ -302,9 +303,9 @@ def flash_bwd_chunk(q, k, v, out, lse, do, *, causal: bool = False,
     outf = _fold_pad(out, bq, d_pad)
     dof = _fold_pad(do, bq, d_pad)
     lq_pad = qf.shape[1]
-    lsef = lse.reshape(b * hq, lq)
+    lsef = lse.reshape(b * hq, lq, 1)
     if lq_pad != lq:
-        lsef = jnp.pad(lsef, ((0, 0), (0, lq_pad - lq)))
+        lsef = jnp.pad(lsef, ((0, 0), (0, lq_pad - lq), (0, 0)))
     doc = None if q_doc_start is None else _pad_doc(q_doc_start, lq, bq)
     dqf, dkf, dvf = _bwd(qf, kf, vf, outf, lsef, dof, p, band=scalars,
                          doc=doc)

@@ -12,7 +12,16 @@ remat, microbatching — are made once by ``build_plan`` and printed via
         --grad-accum 4 --ckpt-dir /tmp/ckpt --save-every 20 [--smoke]
 
 ``--smoke`` swaps in the reduced config + a 1-device mesh — the same code
-path end to end, laptop-sized.
+path end to end, laptop-sized.  Otherwise the grid spans every attached
+device: ``--hp`` (default: the config's head parallelism, capped to what
+the device count divides) × ``cp = devices / hp`` context ranks, with
+``--inner`` ranks on the inner ring (default ``gcd(cp, 4)``).  So
+
+    python -m repro.launch.train --arch qwen3-1.7b --hp 2 --seq-len 8192 \
+        --global-batch 4 --steps 3
+
+runs hp2 × cp2 on a four-chip host.  Compiled programs go to the
+persistent cache (``repro.runtime.compile_cache``).
 
 Checkpointing (``--ckpt-dir``): async per-shard saves every
 ``--save-every`` steps through the plan-aware ``CheckpointManager``;
@@ -43,16 +52,31 @@ from __future__ import annotations
 
 import argparse
 import logging
+import math
 
 import jax
 
 from repro.configs import get_config, get_parallel, get_reduced
 from repro.core.plan import build_plan
-from repro.core.topology import ParallelConfig
+from repro.core.topology import ParallelConfig, factor_cp
 from repro.launch import args as launch_args
 from repro.launch.args import resolve_tuned   # noqa: F401  (re-export)
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.train.optimizer import OptConfig
 from repro.train.trainer import Trainer, TrainerConfig
+
+
+def device_grid(base: ParallelConfig, n_devices: int, *,
+                hp: int | None = None, inner: int | None = None,
+                placement: str | None = None) -> ParallelConfig:
+    """hp × cp over ``n_devices`` (dp = 1): ``hp`` defaults to the
+    config's head parallelism capped to a divisor of the device count."""
+    hp = hp or math.gcd(base.hp, n_devices)
+    assert n_devices % hp == 0, f"--hp {hp} does not divide {n_devices} " \
+        "devices"
+    cp_outer, cp_inner = factor_cp(n_devices // hp, inner)
+    return ParallelConfig(hp=hp, cp_outer=cp_outer, cp_inner=cp_inner,
+                          placement=placement or base.placement)
 
 
 def main():
@@ -94,6 +118,7 @@ def main():
     logging.basicConfig(level=logging.INFO)
     if args.distributed:
         jax.distributed.initialize()
+    enable_compile_cache()
 
     if args.smoke:
         cfg = get_reduced(args.arch)
@@ -102,13 +127,9 @@ def main():
         seq, gb = min(args.seq_len, 128), min(args.global_batch, 8)
     else:
         cfg = get_config(args.arch)
-        pc = get_parallel(args.arch, "train_4k", False)
-        if args.hp:
-            inner = args.inner or min(16 // args.hp, 4)
-            cp = 16 // args.hp
-            pc = ParallelConfig(dp=pc.dp, hp=args.hp, cp_outer=cp // inner,
-                                cp_inner=inner,
-                                placement=args.placement or pc.placement)
+        pc = device_grid(get_parallel(args.arch, "train_4k", False),
+                         len(jax.devices()), hp=args.hp, inner=args.inner,
+                         placement=args.placement)
         devices = None
         seq, gb = args.seq_len, args.global_batch
 

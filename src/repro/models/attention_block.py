@@ -17,7 +17,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.attention2d import (Attn2DConfig, attention_2d,
                                     attn2d_config)
-from repro.core.runtime import shard_map_compat as _shard_map
 from repro.core.runtime import Runtime
 from repro.core.topology import (AXIS_HP, AXIS_INNER, AXIS_OUTER, BATCH_AXES,
                                  SEQ_AXES)
@@ -198,7 +197,8 @@ def cross_attn_apply(p, x, enc, rt: Runtime, *, n_heads: int,
         return out
 
     spec = P(BATCH_AXES, SEQ_AXES, None, None)
-    out = _shard_map(local, rt.mesh, (spec, spec, spec), spec)(q, k, v)
+    out = jax.shard_map(local, mesh=rt.mesh, in_specs=(spec, spec, spec),
+                        out_specs=spec, check_vma=False)(q, k, v)
     out = checkpoint_name(out, "attn_out")
     return linear_apply(p["wo"], out.reshape(b, s, n_heads * head_dim))
 
@@ -268,5 +268,7 @@ def decode_attention(q, k_cache, v_cache, pos, rt: Runtime, *,
     spec_kv = P(rt.batch_axes, (AXIS_OUTER, AXIS_INNER),
                 None if kv_replicated else AXIS_HP, None)
     spec_x = tuple(P(rt.batch_axes) if e.ndim else P() for e in extras)
-    return _shard_map(local, rt.mesh, (spec_q, spec_kv, spec_kv) + spec_x,
-                      spec_q)(q, k_cache, v_cache, *extras)
+    return jax.shard_map(local, mesh=rt.mesh,
+                         in_specs=(spec_q, spec_kv, spec_kv) + spec_x,
+                         out_specs=spec_q, check_vma=False)(
+        q, k_cache, v_cache, *extras)

@@ -358,11 +358,12 @@ def _cross_decode(p, x, cache, rt, cfg: ModelConfig):
     def local(q, k, v):
         return flash_attention(q, k, v, causal=False, impl="ref")
 
-    from repro.core.runtime import shard_map_compat as _shard_map
     spec_q = P(rt.batch_axes, None, AXIS_HP, None)
     spec_kv = P(rt.batch_axes, None, AXIS_HP, None)
-    out = _shard_map(local, rt.mesh, (spec_q, spec_kv, spec_kv),
-                     spec_q)(q, cache["k"], cache["v"])
+    out = jax.shard_map(local, mesh=rt.mesh,
+                        in_specs=(spec_q, spec_kv, spec_kv),
+                        out_specs=spec_q, check_vma=False)(
+        q, cache["k"], cache["v"])
     return linear_apply(p["wo"], out.reshape(b, 1, h * hd))
 
 

@@ -33,7 +33,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core.runtime import shard_map_compat as _shard_map
 from repro.core.runtime import Runtime
 from repro.core.topology import BATCH_AXES, SEQ_AXES
 from repro.models.attention_block import (AttnKind, MLADims, cross_attn_apply,
@@ -502,27 +501,23 @@ def chunked_xent(x, w_head, labels, rt: Runtime, cfg: ModelConfig):
                 logits, jnp.maximum(lc, 0)[:, None], axis=1)[:, 0]
             valid = (lc >= 0)
             loss = jnp.where(valid, lse - ll, 0.0)
-            # (1,)-shaped carries: 0-d values saved as shard_map grad
-            # residuals crash legacy shard_map's partial eval (its scalar-
-            # residual promotion misses them), so keep a singleton axis.
-            return (carry[0] + loss.sum(keepdims=True),
-                    carry[1] + valid.sum(keepdims=True).astype(jnp.float32)
-                    ), None
+            return (carry[0] + loss.sum(),
+                    carry[1] + valid.sum().astype(jnp.float32)), None
 
         xs = (xt.reshape(t // chunk, chunk, d),
               lt.reshape(t // chunk, chunk))
+        zero = jnp.zeros((), jnp.float32)
         (loss_sum, n_valid), _ = maybe_scan(
-            jax.checkpoint(chunk_fn), (jnp.zeros((1,), jnp.float32),
-                                       jnp.zeros((1,), jnp.float32)), xs,
-            cfg.unroll_loops)
-        loss_sum = lax.psum(loss_sum[0], BATCH_AXES + SEQ_AXES)
-        n_valid = lax.psum(n_valid[0], BATCH_AXES + SEQ_AXES)
+            jax.checkpoint(chunk_fn), (zero, zero), xs, cfg.unroll_loops)
+        loss_sum = lax.psum(loss_sum, BATCH_AXES + SEQ_AXES)
+        n_valid = lax.psum(n_valid, BATCH_AXES + SEQ_AXES)
         return loss_sum, n_valid
 
     spec_x = P(BATCH_AXES, SEQ_AXES, None)
     spec_l = P(BATCH_AXES, SEQ_AXES)
-    f = _shard_map(local, rt.mesh, (spec_x, P(None, None), spec_l),
-                   (P(), P()))
+    f = jax.shard_map(local, mesh=rt.mesh,
+                      in_specs=(spec_x, P(None, None), spec_l),
+                      out_specs=(P(), P()), check_vma=False)
     return f(x, w_head, labels)
 
 

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core.runtime import shard_map_compat as _shard_map
 from repro.core.runtime import Runtime
 from repro.core.topology import (AXIS_HP, AXIS_INNER, AXIS_OUTER, BATCH_AXES,
                                  MESH_AXES, SEQ_AXES)
@@ -139,9 +138,10 @@ def moe_apply(p, x, rt: Runtime, m: MoEDims, seq_sharded: bool = True):
     spec_x = P(rt.batch_axes, SEQ_AXES, None) if seq_sharded \
         else P(rt.batch_axes, None, None)
     spec_e = P(EP_AXES, None, None)
-    f = _shard_map(local, rt.mesh,
-                   (spec_x, P(None, None), spec_e, spec_e, spec_e),
-                   (spec_x, P()))
+    f = jax.shard_map(local, mesh=rt.mesh,
+                      in_specs=(spec_x, P(None, None), spec_e, spec_e,
+                                spec_e),
+                      out_specs=(spec_x, P()), check_vma=False)
     y, aux = f(x, p["router"], p["w1"], p["w3"], p["w2"])
 
     if m.n_shared:

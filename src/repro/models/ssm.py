@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core.runtime import axis_size_compat
-from repro.core.runtime import shard_map_compat as _shard_map
 from repro.core.runtime import Runtime
 from repro.core.topology import BATCH_AXES, SEQ_AXES
 from repro.models.layers import (init_linear, init_rmsnorm, linear_apply,
@@ -140,7 +138,7 @@ def _cross_rank_state(d_tot, h_fin, axes, n_ranks: int):
 def _linear_rank(axes):
     idx = lax.axis_index(axes[0])
     for a in axes[1:]:
-        idx = idx * axis_size_compat(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
@@ -221,7 +219,8 @@ def mamba1_apply(p, x, rt: Runtime, m: Mamba1Dims,
                                         x_in.dtype))
 
     spec = P(BATCH_AXES, SEQ_AXES, None)
-    x_conv = _shard_map(conv_local, rt.mesh, (spec,), spec)(x_in)
+    x_conv = jax.shard_map(conv_local, mesh=rt.mesh, in_specs=(spec,),
+                           out_specs=spec, check_vma=False)(x_in)
 
     dbc = linear_apply(p["x_proj"], x_conv)
     dt = jax.nn.softplus(
@@ -250,9 +249,10 @@ def mamba1_apply(p, x, rt: Runtime, m: Mamba1Dims,
         corr = jnp.einsum("bsdn,bdn,bsn->bsd", decay, h_init, cmat)
         return (y0 + corr).astype(x_conv.dtype), h_last
 
-    y, h_last = _shard_map(scan_local, rt.mesh, (spec,) * 4,
-                           (spec, P(BATCH_AXES, None, None)))(
-        dt, bmat, cmat, x_conv)
+    y, h_last = jax.shard_map(
+        scan_local, mesh=rt.mesh, in_specs=(spec,) * 4,
+        out_specs=(spec, P(BATCH_AXES, None, None)),
+        check_vma=False)(dt, bmat, cmat, x_conv)
     y = y + x_conv * p["D"].astype(x_conv.dtype)
     y = y * jax.nn.silu(z)
     out = linear_apply(p["out_proj"], y)
@@ -370,7 +370,8 @@ def mamba2_apply(p, x, rt: Runtime, m: Mamba2Dims,
         return jax.nn.silu(_causal_conv(xp, p["conv_w"], p["conv_b"],
                                         xbc.dtype))
 
-    xbc = _shard_map(conv_local, rt.mesh, (spec3,), spec3)(xbc_pre)
+    xbc = jax.shard_map(conv_local, mesh=rt.mesh, in_specs=(spec3,),
+                        out_specs=spec3, check_vma=False)(xbc_pre)
     x_in = xbc[..., :m.d_inner]
     bmat = xbc[..., m.d_inner:m.d_inner + m.d_state].astype(jnp.float32)
     cmat = xbc[..., m.d_inner + m.d_state:].astype(jnp.float32)
@@ -396,9 +397,10 @@ def mamba2_apply(p, x, rt: Runtime, m: Mamba2Dims,
         y = y0 + corr
         return (y.reshape(bsz, s_loc, m.d_inner).astype(x_in.dtype), h_last)
 
-    y, h_last = _shard_map(scan_local, rt.mesh, (spec3,) * 4,
-                           (spec3, P(BATCH_AXES, None, None, None)))(
-        dt, bmat, cmat, x_in)
+    y, h_last = jax.shard_map(
+        scan_local, mesh=rt.mesh, in_specs=(spec3,) * 4,
+        out_specs=(spec3, P(BATCH_AXES, None, None, None)),
+        check_vma=False)(dt, bmat, cmat, x_in)
     d_rep = jnp.repeat(p["D"], m.head_dim).astype(x_in.dtype)
     y = y + x_in * d_rep
     y = rmsnorm_apply(p["norm"], y * jax.nn.silu(z))

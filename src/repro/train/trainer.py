@@ -52,13 +52,20 @@ class Trainer:
         self.guard = PreemptionGuard()
         self.guard.install()
 
+        # params and moments are created in place at their ZeRO shardings
+        # (never whole on one device first: at published widths the fp32
+        # masters plus moments exceed one chip's memory)
+        key = jax.random.PRNGKey(tcfg.seed)
         with plan.mesh:
-            params = init_params(plan.cfg, jax.random.PRNGKey(tcfg.seed))
+            shapes = jax.eval_shape(lambda: init_params(plan.cfg, key))
             self.step_fn, self.p_sh, self.o_sh = jit_train_step(plan,
-                                                                params)
-            self.params = jax.device_put(params, self.p_sh)
-            self.opt_state = jax.device_put(init_opt_state(params),
-                                            self.o_sh)
+                                                                shapes)
+            self.params = jax.jit(lambda: init_params(plan.cfg, key),
+                                  out_shardings=self.p_sh)()
+            self.opt_state = jax.jit(init_opt_state,
+                                     out_shardings=self.o_sh)(self.params)
+        #: ``{"step", "loss", "grad_norm"}`` at every ``log_every`` sync
+        self.history: list[dict] = []
         self.start_step = 0
         self.ckpter = None
         if tcfg.ckpt_dir:
@@ -99,13 +106,17 @@ class Trainer:
                 if step % self.tcfg.log_every == 0:
                     # the only in-loop host sync; step time is amortized
                     # over the steps dispatched since the previous sync
-                    loss = float(metrics["loss"])
+                    jax.block_until_ready((self.params, self.opt_state,
+                                           metrics))
                     n_flagged = len(self.monitor.flagged)
                     self.monitor.lap(pending)
                     pending = 0
+                    loss = float(metrics["loss"])
+                    gnorm = float(metrics["grad_norm"])
+                    self.history.append({"step": step, "loss": loss,
+                                         "grad_norm": gnorm})
                     log.info("step %d loss %.4f gnorm %.3f (%.2fs/step)",
-                             step, loss, float(metrics["grad_norm"]),
-                             self.monitor.median)
+                             step, loss, gnorm, self.monitor.median)
                     for s, dt, med in self.monitor.flagged[n_flagged:]:
                         log.warning("straggler flagged at step %d: "
                                     "%.3fs vs median %.3fs", s, dt, med)

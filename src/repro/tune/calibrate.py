@@ -61,7 +61,6 @@ def run_microbenchmarks(n: int = 1024) -> dict:
     devs = jax.devices()
     if len(devs) > 1:
         mesh = jax.make_mesh((len(devs),), ("ring",))
-        from repro.core.runtime import shard_map_compat
         from jax.sharding import PartitionSpec as P
 
         def hop(a):
@@ -69,7 +68,8 @@ def run_microbenchmarks(n: int = 1024) -> dict:
             return jax.lax.ppermute(a, "ring", pairs)
 
         chunk = jnp.zeros((len(devs), n, n), jnp.float32)
-        f = jax.jit(shard_map_compat(hop, mesh, (P("ring"),), P("ring")))
+        f = jax.jit(jax.shard_map(hop, mesh=mesh, in_specs=(P("ring"),),
+                                  out_specs=P("ring"), check_vma=False))
         jax.block_until_ready(f(chunk))
         t = _time_best(lambda: jax.block_until_ready(f(chunk)))
         coll_bw = n * n * 4 / t               # per-device chunk over wire

@@ -505,7 +505,6 @@ def check_packed_parity():
 def check_grad_compression():
     """int8 error-feedback psum inside shard_map over the data axis."""
     from jax.sharding import PartitionSpec as P
-    from repro.core.runtime import shard_map_compat as _shard_map
     from repro.core.topology import ParallelConfig, make_mesh, AXIS_DATA
     from repro.train.optimizer import compressed_psum
 
@@ -518,8 +517,10 @@ def check_grad_compression():
         s, e2 = compressed_psum(g, e, AXIS_DATA)
         return s, e2
 
-    f = _shard_map(local, mesh, (P(AXIS_DATA, None), P(AXIS_DATA, None)),
-                   (P(None, None), P(AXIS_DATA, None)))
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P(AXIS_DATA, None), P(AXIS_DATA, None)),
+                      out_specs=(P(None, None), P(AXIS_DATA, None)),
+                      check_vma=False)
     # accumulate over steps: error feedback should keep the running sum
     # close to the exact running sum
     exact_acc = np.zeros((1, 64))

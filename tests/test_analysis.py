@@ -103,3 +103,11 @@ def test_count_params_moe_active():
     assert active < total  # experts discounted by top_k / n_experts
     dense_total, dense_active = count_params(get_reduced("olmo-1b"))
     assert dense_total == dense_active
+
+
+def test_peak_flops_by_device_kind():
+    from repro.analysis.cost import PEAK_BF16_FLOPS, V5E, peak_flops
+    assert peak_flops("TPU v5 lite") == V5E.peak == 197e12
+    assert set(PEAK_BF16_FLOPS) == {"TPU v5 lite"}
+    with pytest.raises(KeyError, match="TPU v9"):
+        peak_flops("TPU v9")

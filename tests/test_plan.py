@@ -1,6 +1,7 @@
 """ExecutionPlan: placement orderings, AMSP ZeRO selection, sub-group
 fallback, describe(), and microbatched gradient accumulation."""
 import numpy as np
+import pytest
 import jax
 import jax.numpy as jnp
 
@@ -296,3 +297,18 @@ def test_serve_spec_reuses_offload_accounting():
     # the logical per-token bytes are unchanged: only residency moved
     assert sv8.paged_bytes_per_token == sv1.paged_bytes_per_token
     assert sv8.max_blocks_per_seq == sv1.max_blocks_per_seq
+
+
+@pytest.mark.parametrize("n,hp,want", [
+    (4, 2, (2, 1, 2)),        # --hp 2 on a four-chip host: hp2 × cp2 (w=2)
+    (4, None, (4, 1, 1)),     # config hp8 capped to the 4 chips
+    (1, None, (1, 1, 1)),     # one chip
+    (16, 2, (2, 2, 4)),       # cp8 = outer 2 × inner 4
+])
+def test_launcher_grid_from_device_count(n, hp, want):
+    from repro.configs import get_parallel
+    from repro.launch.train import device_grid
+    pc = device_grid(get_parallel("qwen3-1.7b", "train_4k", False), n,
+                     hp=hp)
+    assert (pc.hp, pc.cp_outer, pc.cp_inner) == want
+    assert pc.dp == 1 and pc.num_devices == n

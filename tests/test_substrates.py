@@ -59,10 +59,7 @@ def test_zero_leaf_rules():
     # leaf_spec only reads mesh.shape — an abstract 8-way mesh suffices
     names = ("pod", "data", "head", "outer", "inner")
     sizes = (1, 2, 2, 1, 2)
-    try:
-        mesh = jax.sharding.AbstractMesh(sizes, names)
-    except TypeError:   # older spelling: tuple of (name, size) pairs
-        mesh = jax.sharding.AbstractMesh(tuple(zip(names, sizes)))
+    mesh = jax.sharding.AbstractMesh(sizes, names)
     # big leaf divisible by full group (8) -> sharded on largest dim
     spec = leaf_spec((128, 512), mesh)
     assert spec[1] is not None

@@ -1,0 +1,95 @@
+"""The main path's Pallas flash kernels compiled for a described TPU v5e.
+
+Nothing runs: each case lowers and compiles for a chip that the TPU
+compiler describes (``v5e:2x2``) without one attached, and asserts that
+the program holds the kernel (``tpu_custom_call``).  This catches what
+interpret mode cannot: block shapes the TPU lowering refuses, VMEM over-
+use, a kernel that cannot be partitioned.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, so describing it while the file is
+collected would break every other pytest worker.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.ops import flash_attention, flash_bwd_chunk, flash_fwd_chunk
+from repro.kernels.ref import BandMask
+
+#: qwen3-1.7b attention widths
+L, HQ, HKV, D = 4096, 16, 8, 128
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """A ``SingleDeviceSharding`` on one described v5e chip; the persistent
+    compile cache is off meanwhile (entries compiled for a described chip
+    cannot be read back without one)."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                          # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _qkv(chip, batch, seq=L, hq=HQ, hkv=HKV):
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=chip)
+    return (sds(batch, seq, hq, D), sds(batch, seq, hkv, D),
+            sds(batch, seq, hkv, D))
+
+
+def _loss(q, k, v, doc=None):
+    return flash_attention(q, k, v, causal=True, q_doc_start=doc,
+                           impl="pallas").astype(jnp.float32).sum()
+
+
+def test_fwd_qwen3_widths(chip):
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                             impl="pallas"),
+             *_qkv(chip, 1))
+
+
+def test_gqa_fwd_bwd(chip):
+    _compile(jax.grad(_loss, argnums=(0, 1, 2)), *_qkv(chip, 1))
+
+
+def test_packed_fwd_bwd_batch2(chip):
+    doc = jax.ShapeDtypeStruct((2, L), jnp.int32, sharding=chip)
+    _compile(jax.grad(_loss, argnums=(0, 1, 2)), *_qkv(chip, 2), doc)
+
+
+def test_ring_step_traced_band(chip):
+    """One Double-Ring step of a cp=2, hp=2 shard: zigzag band offsets
+    from traced rank indices ride into both kernels as scalar prefetch."""
+    cp, s_loc = 2, L // 2
+    q, k, v = _qkv(chip, 1, seq=s_loc, hq=HQ // 2, hkv=HKV // 2)
+    rank = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
+
+    def step(q, k, v, i, j):
+        band = BandMask.zigzag(i, j, s_loc // 2, cp)
+        out, lse = flash_fwd_chunk(q, k, v, causal=True, band=band,
+                                   impl="pallas")
+        grads = flash_bwd_chunk(q, k, v, out, lse, out, causal=True,
+                                band=band, impl="pallas")
+        return out, lse, grads
+
+    _compile(step, q, k, v, rank, rank)
