@@ -54,6 +54,7 @@ from repro.core.topology import (AXIS_HP, AXIS_INNER, AXIS_OUTER, BATCH_AXES,
 from repro.core.zigzag import from_zigzag, to_zigzag
 from repro.kernels.ops import flash_attention, flash_bwd_chunk, flash_fwd_chunk
 from repro.kernels.ref import BandMask, combine_pair
+from repro.runtime import spans
 
 
 class Attn2DConfig(NamedTuple):
@@ -183,6 +184,7 @@ def _step_fwd(q, kc, vc, doc, o: int, t: int, i_out, i_in, i,
                            q_doc_start=doc, **kw)
 
 
+@jax.named_scope(spans.RING)
 def _ring_fwd(q, k, v, doc, cfg: RingConfig, qb=0, kb=0):
     i_out, i_in, i = _ring_indices(cfg)
     acc_o = None
@@ -235,6 +237,7 @@ def _step_bwd(q, kc, vc, out, lse, do, doc, o: int, t: int, i_out, i_in, i,
                            q_doc_start=doc, **kw)
 
 
+@jax.named_scope(spans.RING)
 def _ring_bwd(q, k, v, out, lse, do, doc, cfg: RingConfig, qb=0, kb=0):
     i_out, i_in, i = _ring_indices(cfg)
     dq = jnp.zeros(q.shape, jnp.float32)
@@ -326,12 +329,13 @@ def attention_2d_local(q, k, v, cfg: Attn2DConfig, doc_start=None):
 
     if cfg.hp > 1:
         assert hq % cfg.hp == 0, (hq, cfg.hp)
-        q = lax.all_to_all(q, cfg.axis_hp, 2, 1, tiled=True)
-        k = lax.all_to_all(k, cfg.axis_hp, 2, 1, tiled=True)
-        v = lax.all_to_all(v, cfg.axis_hp, 2, 1, tiled=True)
-        if doc_start is not None:
-            doc_start = lax.all_gather(doc_start, cfg.axis_hp, axis=1,
-                                       tiled=True)
+        with jax.named_scope(spans.ULYSSES_A2A):
+            q = lax.all_to_all(q, cfg.axis_hp, 2, 1, tiled=True)
+            k = lax.all_to_all(k, cfg.axis_hp, 2, 1, tiled=True)
+            v = lax.all_to_all(v, cfg.axis_hp, 2, 1, tiled=True)
+            if doc_start is not None:
+                doc_start = lax.all_gather(doc_start, cfg.axis_hp, axis=1,
+                                           tiled=True)
 
     if cfg.cp == 1:
         out = flash_attention(q, k, v, causal=cfg.causal, window=cfg.window,
@@ -347,7 +351,8 @@ def attention_2d_local(q, k, v, cfg: Attn2DConfig, doc_start=None):
         out = ring_attention(q, k, v, doc_start, rcfg)
 
     if cfg.hp > 1:
-        out = lax.all_to_all(out, cfg.axis_hp, 1, 2, tiled=True)
+        with jax.named_scope(spans.ULYSSES_A2A):
+            out = lax.all_to_all(out, cfg.axis_hp, 1, 2, tiled=True)
     return out
 
 
@@ -416,15 +421,17 @@ def _chunk_pair_fwd_local(q, k, v, doc, qb, kb, cfg: Attn2DConfig):
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     if cfg.hp > 1:
-        q = lax.all_to_all(q, cfg.axis_hp, 2, 1, tiled=True)
-        k = lax.all_to_all(k, cfg.axis_hp, 2, 1, tiled=True)
-        v = lax.all_to_all(v, cfg.axis_hp, 2, 1, tiled=True)
-        if doc is not None:
-            doc = lax.all_gather(doc, cfg.axis_hp, axis=1, tiled=True)
+        with jax.named_scope(spans.ULYSSES_A2A):
+            q = lax.all_to_all(q, cfg.axis_hp, 2, 1, tiled=True)
+            k = lax.all_to_all(k, cfg.axis_hp, 2, 1, tiled=True)
+            v = lax.all_to_all(v, cfg.axis_hp, 2, 1, tiled=True)
+            if doc is not None:
+                doc = lax.all_gather(doc, cfg.axis_hp, axis=1, tiled=True)
     out, lse = _ring_fwd(q, k, v, doc, rcfg, qb, kb)
     if cfg.hp > 1:
-        out = lax.all_to_all(out, cfg.axis_hp, 1, 2, tiled=True)
-        lse = lax.all_to_all(lse, cfg.axis_hp, 2, 1, tiled=True)
+        with jax.named_scope(spans.ULYSSES_A2A):
+            out = lax.all_to_all(out, cfg.axis_hp, 1, 2, tiled=True)
+            lse = lax.all_to_all(lse, cfg.axis_hp, 2, 1, tiled=True)
     return out, lse
 
 
@@ -444,15 +451,18 @@ def _chunk_pair_bwd_local(q, k, v, out, lse, do, doc, qb, kb,
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     if cfg.hp > 1:
-        q, k, v, out, do = (lax.all_to_all(x, cfg.axis_hp, 2, 1, tiled=True)
-                            for x in (q, k, v, out, do))
-        lse = lax.all_to_all(lse, cfg.axis_hp, 1, 2, tiled=True)
-        if doc is not None:
-            doc = lax.all_gather(doc, cfg.axis_hp, axis=1, tiled=True)
+        with jax.named_scope(spans.ULYSSES_A2A):
+            q, k, v, out, do = (lax.all_to_all(x, cfg.axis_hp, 2, 1,
+                                               tiled=True)
+                                for x in (q, k, v, out, do))
+            lse = lax.all_to_all(lse, cfg.axis_hp, 1, 2, tiled=True)
+            if doc is not None:
+                doc = lax.all_gather(doc, cfg.axis_hp, axis=1, tiled=True)
     dq, dk, dv = _ring_bwd(q, k, v, out, lse, do, doc, rcfg, qb, kb)
     if cfg.hp > 1:
-        dq, dk, dv = (lax.all_to_all(x, cfg.axis_hp, 1, 2, tiled=True)
-                      for x in (dq, dk, dv))
+        with jax.named_scope(spans.ULYSSES_A2A):
+            dq, dk, dv = (lax.all_to_all(x, cfg.axis_hp, 1, 2, tiled=True)
+                          for x in (dq, dk, dv))
     if rep > 1:
         bb, ss, _, dd = dk.shape
         # jnp.repeat is consecutive, so replica grads group-sum by reshape.

@@ -49,6 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import _logical_pos
+from repro.runtime import spans
 
 NEG_INF = -1e30
 
@@ -263,6 +264,7 @@ def _fwd(q, k, v, p: FlashParams, band=None, doc=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=p.interpret,
+        name=spans.FLASH_FWD,
     )(band, *operands)
     return out, lse
 
@@ -429,6 +431,7 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=p.interpret,
+        name=spans.FLASH_DQ,
     )(band, *operands)
 
     # Query-side operands walk b*group + ig//nq: for a fixed KV head, the
@@ -476,6 +479,7 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=p.interpret,
+        name=spans.FLASH_DKV,
     )(band, *operands)
     return dq, dk, dv
 

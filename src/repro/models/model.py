@@ -48,6 +48,7 @@ from repro.models.layers import (embedding_apply, gelu_mlp_apply,
 from repro.models.moe import MoEDims, init_moe, moe_apply
 from repro.models.ssm import (Mamba1Dims, Mamba2Dims, init_mamba1,
                               init_mamba2, mamba1_apply, mamba2_apply)
+from repro.runtime import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,23 +213,25 @@ def apply_transformer_block(p, x, ropes, rt: Runtime, cfg: ModelConfig,
     """Returns (x, aux_loss)."""
     cos, sin = ropes[kind.rope_theta]
     h = apply_norm(cfg, p["ln1"], x)
-    if cfg.mla is not None:
-        h = mla_apply(p["attn"], h, cos, sin, rt, kind, cfg.mla,
-                      zigzag=cfg.zigzag, doc_start=doc_start)
-    else:
-        h = gqa_apply(p["attn"], h, cos, sin, rt, kind,
-                      n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-                      head_dim=cfg.hd, qk_norm=cfg.qk_norm,
-                      zigzag=cfg.zigzag, doc_start=doc_start)
+    with jax.named_scope(spans.ATTN):
+        if cfg.mla is not None:
+            h = mla_apply(p["attn"], h, cos, sin, rt, kind, cfg.mla,
+                          zigzag=cfg.zigzag, doc_start=doc_start)
+        else:
+            h = gqa_apply(p["attn"], h, cos, sin, rt, kind,
+                          n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                          head_dim=cfg.hd, qk_norm=cfg.qk_norm,
+                          zigzag=cfg.zigzag, doc_start=doc_start)
     if cfg.post_norms:
         h = apply_norm(cfg, p["pn1"], h)
     x = x + h
     h = apply_norm(cfg, p["ln2"], x)
     aux = jnp.zeros((), jnp.float32)
-    if moe_layer:
-        h, aux = moe_apply(p["moe"], h, rt, cfg.moe)
-    else:
-        h = glu_mlp_apply(p["mlp"], h, act=cfg.act)
+    with jax.named_scope(spans.MLP):
+        if moe_layer:
+            h, aux = moe_apply(p["moe"], h, rt, cfg.moe)
+        else:
+            h = glu_mlp_apply(p["mlp"], h, act=cfg.act)
     if cfg.post_norms:
         h = apply_norm(cfg, p["pn2"], h)
     return x + h, aux
@@ -433,11 +436,14 @@ def whisper_encoder(params, frames, rt: Runtime, cfg: ModelConfig):
 
     def body(x, lp):
         h = apply_norm(cfg, lp["ln1"], x)
-        h = gqa_apply(lp["attn"], h, None, None, rt, kind,
-                      n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-                      head_dim=cfg.hd, zigzag=False)
+        with jax.named_scope(spans.ATTN):
+            h = gqa_apply(lp["attn"], h, None, None, rt, kind,
+                          n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                          head_dim=cfg.hd, zigzag=False)
         x = x + h
-        h = gelu_mlp_apply(lp["mlp"], apply_norm(cfg, lp["ln2"], x))
+        h = apply_norm(cfg, lp["ln2"], x)
+        with jax.named_scope(spans.MLP):
+            h = gelu_mlp_apply(lp["mlp"], h)
         return x + h, jnp.zeros((), jnp.float32)
 
     x, _, _ = _scan_blocks(body, x, params["enc_blocks"], policy,
@@ -455,15 +461,19 @@ def whisper_decoder(params, x, enc_out, ropes, rt: Runtime,
 
     def body(x, lp):
         h = apply_norm(cfg, lp["ln1"], x)
-        h = gqa_apply(lp["attn"], h, None, None, rt, kind,
-                      n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-                      head_dim=cfg.hd, zigzag=cfg.zigzag)
+        with jax.named_scope(spans.ATTN):
+            h = gqa_apply(lp["attn"], h, None, None, rt, kind,
+                          n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                          head_dim=cfg.hd, zigzag=cfg.zigzag)
         x = x + h
-        h = cross_attn_apply(lp["cross"], apply_norm(cfg, lp["lnx"], x),
-                             enc_out, rt, n_heads=cfg.n_heads,
-                             head_dim=cfg.hd)
+        h = apply_norm(cfg, lp["lnx"], x)
+        with jax.named_scope(spans.ATTN):
+            h = cross_attn_apply(lp["cross"], h, enc_out, rt,
+                                 n_heads=cfg.n_heads, head_dim=cfg.hd)
         x = x + h
-        h = gelu_mlp_apply(lp["mlp"], apply_norm(cfg, lp["ln2"], x))
+        h = apply_norm(cfg, lp["ln2"], x)
+        with jax.named_scope(spans.MLP):
+            h = gelu_mlp_apply(lp["mlp"], h)
         return x + h, jnp.zeros((), jnp.float32)
 
     x, _, _ = _scan_blocks(body, x, params["dec_blocks"], policy,
@@ -482,6 +492,7 @@ def chunked_xent(x, w_head, labels, rt: Runtime, cfg: ModelConfig):
     """
     cap = cfg.final_softcap
 
+    @jax.named_scope(spans.LM_HEAD)
     def local(x, w, labels):
         b_loc, s_loc, d = x.shape
         t = b_loc * s_loc

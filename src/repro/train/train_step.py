@@ -26,6 +26,7 @@ from jax import lax
 
 from repro.core.plan import ExecutionPlan
 from repro.models.model import cast_params_once, forward_loss
+from repro.runtime import spans
 from repro.train.optimizer import adamw_update
 
 
@@ -70,8 +71,9 @@ def make_train_step(plan: ExecutionPlan):
                        for k, v in ms.items()}
         grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads_half,
                              params)
-        new_params, new_state, om = adamw_update(params, grads, opt_state,
-                                                 opt_cfg)
+        with jax.named_scope(spans.OPTIMIZER):
+            new_params, new_state, om = adamw_update(params, grads,
+                                                     opt_state, opt_cfg)
         metrics = dict(metrics)
         metrics.update(om)
         return new_params, new_state, metrics

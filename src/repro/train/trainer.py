@@ -18,6 +18,7 @@ from repro.core.plan import ExecutionPlan
 from repro.data.pipeline import DataConfig, PackedLM, SyntheticLM
 from repro.models.model import init_params
 from repro.runtime import checkpoint as ckpt
+from repro.runtime import spans
 from repro.runtime.resilience import PreemptionGuard, StepMonitor
 from repro.train.optimizer import init_opt_state
 from repro.train.train_step import jit_train_step
@@ -87,54 +88,72 @@ class Trainer:
     def save(self, step: int):
         if self.ckpter is None:
             return
-        self.ckpter.save_async({"params": self.params,
-                                "opt": self.opt_state}, step)
+        with jax.profiler.TraceAnnotation(spans.CKPT):
+            self.ckpter.save_async({"params": self.params,
+                                    "opt": self.opt_state}, step)
+
+    def _flush(self):
+        if self.ckpter:
+            with jax.profiler.TraceAnnotation(spans.CKPT):
+                self.ckpter.flush()
 
     def run(self):
+        """Steps ``start_step .. num_steps - 1``; returns their losses.
+
+        Under a ``jax.profiler`` capture each step shows as a ``train``
+        step annotation holding the ``train.data``, ``train.dispatch``,
+        ``train.sync`` and ``train.ckpt`` spans (``runtime/spans.py``)."""
         losses = []                    # device scalars until the end
         pending = 0                    # steps dispatched since last sync
         remaining = self.tcfg.num_steps - self.start_step
+        # deterministic resume: the source indexes by step, so a restored
+        # run *skips* to start_step instead of replaying
+        batches = self.data.iter_batches(self.start_step, remaining)
         with self.plan.mesh:
             self.monitor.start()
-            # deterministic resume: the source indexes by step, so a
-            # restored run *skips* to start_step instead of replaying
-            for step, batch in self.data.iter_batches(self.start_step,
-                                                      remaining):
-                self.params, self.opt_state, metrics = self.step_fn(
-                    self.params, self.opt_state, batch)
-                pending += 1
-                if step % self.tcfg.log_every == 0:
-                    # the only in-loop host sync; step time is amortized
-                    # over the steps dispatched since the previous sync
-                    jax.block_until_ready((self.params, self.opt_state,
-                                           metrics))
-                    n_flagged = len(self.monitor.flagged)
-                    self.monitor.lap(pending)
-                    pending = 0
-                    loss = float(metrics["loss"])
-                    gnorm = float(metrics["grad_norm"])
-                    self.history.append({"step": step, "loss": loss,
-                                         "grad_norm": gnorm})
-                    log.info("step %d loss %.4f gnorm %.3f (%.2fs/step)",
-                             step, loss, gnorm, self.monitor.median)
-                    for s, dt, med in self.monitor.flagged[n_flagged:]:
-                        log.warning("straggler flagged at step %d: "
-                                    "%.3fs vs median %.3fs", s, dt, med)
-                losses.append(metrics["loss"])
-                if self.ckpter and (step + 1) % self.tcfg.ckpt_every == 0:
-                    self.save(step + 1)
-                if self.guard.requested:
-                    # SIGTERM landed: flush a final checkpoint at this
-                    # step boundary and stop cleanly
-                    log.warning("preemption requested: flushing "
-                                "checkpoint at step %d", step + 1)
-                    self.save(step + 1)
-                    if self.ckpter:
-                        self.ckpter.flush()
-                    break
-            losses = [float(x) for x in jax.device_get(losses)]
+            for step in range(self.start_step, self.tcfg.num_steps):
+                with jax.profiler.StepTraceAnnotation(spans.STEP,
+                                                      step_num=step):
+                    with jax.profiler.TraceAnnotation(spans.DATA):
+                        _, batch = next(batches)
+                    with jax.profiler.TraceAnnotation(spans.DISPATCH):
+                        self.params, self.opt_state, metrics = self.step_fn(
+                            self.params, self.opt_state, batch)
+                    pending += 1
+                    if step % self.tcfg.log_every == 0:
+                        self._log(step, pending, metrics)
+                        pending = 0
+                    losses.append(metrics["loss"])
+                    if self.ckpter and (step + 1) % self.tcfg.ckpt_every == 0:
+                        self.save(step + 1)
+                    if self.guard.requested:
+                        # SIGTERM landed: flush a final checkpoint at this
+                        # step boundary and stop cleanly
+                        log.warning("preemption requested: flushing "
+                                    "checkpoint at step %d", step + 1)
+                        self.save(step + 1)
+                        self._flush()
+                        break
+            with jax.profiler.TraceAnnotation(spans.SYNC):
+                losses = [float(x) for x in jax.device_get(losses)]
             if pending:                # attribute the synced tail
                 self.monitor.lap(pending)
-        if self.ckpter:
-            self.ckpter.flush()
+        self._flush()
         return losses
+
+    def _log(self, step: int, pending: int, metrics):
+        """The only in-loop host sync; step time is amortized over the
+        ``pending`` steps dispatched since the previous sync."""
+        with jax.profiler.TraceAnnotation(spans.SYNC):
+            jax.block_until_ready((self.params, self.opt_state, metrics))
+            n_flagged = len(self.monitor.flagged)
+            self.monitor.lap(pending)
+            loss = float(metrics["loss"])
+            gnorm = float(metrics["grad_norm"])
+        self.history.append({"step": step, "loss": loss,
+                             "grad_norm": gnorm})
+        log.info("step %d loss %.4f gnorm %.3f (%.2fs/step)",
+                 step, loss, gnorm, self.monitor.median)
+        for s, dt, med in self.monitor.flagged[n_flagged:]:
+            log.warning("straggler flagged at step %d: "
+                        "%.3fs vs median %.3fs", s, dt, med)

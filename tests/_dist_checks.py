@@ -202,6 +202,47 @@ def check_ring_pallas_path():
     print("PASS ring_pallas_path")
 
 
+def check_exchange_scopes():
+    """hp2 x cp2 on four devices, fwd+bwd compiled: every all-to-all
+    carries the ``ulysses_a2a`` scope in its ``op_name`` and every
+    collective-permute the ``ring`` scope (runtime/spans.py), so that a
+    trace of the four-chip grid can split the exchange by its kind."""
+    import re
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.core.topology import (BATCH_AXES, SEQ_AXES, ParallelConfig,
+                                     make_mesh)
+    from repro.core.attention2d import Attn2DConfig, attention_2d
+    from repro.runtime import spans
+
+    pc = ParallelConfig(dp=1, hp=2, cp_outer=1, cp_inner=2)
+    mesh = make_mesh(pc, devices=jax.devices()[:4])
+    cfg = Attn2DConfig(hp=2, n_out=1, w=2, causal=True, impl="ref")
+    sh = NamedSharding(mesh, P(BATCH_AXES, SEQ_AXES, None, None))
+    q = jax.ShapeDtypeStruct((1, 64, 4, 16), jnp.float32, sharding=sh)
+    kv = jax.ShapeDtypeStruct((1, 64, 2, 16), jnp.float32, sharding=sh)
+
+    def loss(q, k, v):
+        return attention_2d(q, k, v, mesh=mesh, cfg=cfg).sum()
+
+    with mesh:
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile().as_text()
+    seen = {}
+    for line in hlo.splitlines():
+        m = re.search(r"= [^=]*? (all-to-all|collective-permute)"
+                      r"(?:-start)?\(", line)
+        if not m:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line).group(1)
+        want = (spans.ULYSSES_A2A if m.group(1) == "all-to-all"
+                else spans.RING)
+        assert re.search(rf"(^|[/(]){want}([/)]|$)", name), (m.group(1),
+                                                              name)
+        seen[m.group(1)] = seen.get(m.group(1), 0) + 1
+    assert seen.get("all-to-all") and seen.get("collective-permute"), seen
+    print("PASS exchange_scopes")
+
+
 def check_ssm():
     from repro.core.topology import ParallelConfig
     from repro.models.ssm import (Mamba1Dims, Mamba2Dims, init_mamba1,

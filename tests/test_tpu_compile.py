@@ -18,6 +18,7 @@ import pytest
 
 from repro.kernels.ops import flash_attention, flash_bwd_chunk, flash_fwd_chunk
 from repro.kernels.ref import BandMask
+from repro.runtime import spans
 
 #: qwen3-1.7b attention widths
 L, HQ, HKV, D = 4096, 16, 8, 128
@@ -70,6 +71,15 @@ def test_fwd_qwen3_widths(chip):
 
 def test_gqa_fwd_bwd(chip):
     _compile(jax.grad(_loss, argnums=(0, 1, 2)), *_qkv(chip, 1))
+
+
+def test_fwd_bwd_kernel_names(chip):
+    """The three kernels carry the names the benchmark's trace readers
+    and the breakdown show (``runtime/spans.py``)."""
+    from bench.trace import kernel_names
+    compiled = _compile(jax.grad(_loss, argnums=(0, 1, 2)), *_qkv(chip, 1))
+    assert set(kernel_names(compiled.as_text()).values()) == {
+        spans.FLASH_FWD, spans.FLASH_DQ, spans.FLASH_DKV}
 
 
 def test_packed_fwd_bwd_batch2(chip):
