@@ -142,6 +142,38 @@ def _tile_mask(q_start, k_start, band_ref, p: FlashParams, doc_ref=None):
     return mask
 
 
+#: Scoped VMEM a Mosaic kernel gets unless its ``CompilerParams`` ask for
+#: more (16 MiB on v5e, of the core's 128 MiB).
+_SCOPED_VMEM = 16 << 20
+#: ``(block_q, block_k)`` fp32 temporaries each grid step may hold at once.
+_FWD_TILES = 4
+_BWD_TILES = 6
+
+
+def _vmem_bytes(p: FlashParams, d: int, n_tiles: int) -> int:
+    """Upper reckoning of one kernel's VMEM: every blocked operand
+    double-buffered at 4 bytes with its last axis padded to 128 lanes (at
+    most 6 q-side and 4 k-side), the fp32 scratch (at most 3 q-side
+    rows), and ``n_tiles`` fp32 score-sized temporaries."""
+    lanes = _round_up(d, 128)
+    blocks = 2 * 4 * lanes * (6 * p.block_q + 4 * p.block_k)
+    scratch = 4 * lanes * 3 * p.block_q
+    return blocks + scratch + n_tiles * 4 * p.block_q * p.block_k
+
+
+def _compiler_params(p: FlashParams, d: int, *, n_tiles: int):
+    """Grid semantics, and a raised VMEM limit where the reckoning of the
+    kernel's tiles passes the scoped default."""
+    need = _vmem_bytes(p, d, n_tiles)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=need if need > _SCOPED_VMEM else None)
+
+
+def _round_up(x: int, m: int) -> int:
+    return (x + m - 1) // m * m
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
@@ -261,8 +293,7 @@ def _fwd(q, k, v, p: FlashParams, band=None, doc=None):
             jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
             jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(p, d, n_tiles=_FWD_TILES),
         interpret=p.interpret,
         name=spans.FLASH_FWD,
     )(band, *operands)
@@ -388,6 +419,18 @@ def _dkv_kernel(band_ref, *refs, p: FlashParams, nq: int, group: int):
 def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
     """Backward in the folded layout.  k/v may have fewer (KV) heads than
     q (GQA); dk/dv come back at the KV head count, group-summed."""
+    if band is None:
+        band = _default_band(p)
+    dsum = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                   axis=-1, keepdims=True)  # (BH, Lq, 1)
+    operands = (q, k, v, do, lse, dsum) + ((doc,) if p.packed else ())
+    dq = _bwd_dq(band, *operands, p=p)
+    dk, dv = _bwd_dkv(band, *operands, p=p)
+    return dq, dk, dv
+
+
+def _bwd_dq(band, q, k, v, do, lse, dsum, doc=None, *, p: FlashParams):
+    """The dq kernel: grid (B·Hq, nq, nk), K blocks sequential."""
     bh, lq, d = q.shape
     bhkv, lk, _ = k.shape
     assert bh % bhkv == 0, (bh, bhkv)
@@ -395,12 +438,7 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
     group = bh // bhkv
     nq = lq // p.block_q
     nk = lk // p.block_k
-    if band is None:
-        band = _default_band(p)
-    dsum = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                   axis=-1, keepdims=True)  # (BH, Lq, 1)
-
-    dq_in_specs = [
+    in_specs = [
         pl.BlockSpec((1, p.block_q, d), lambda b, i, j, s: (b, i, 0)),
         pl.BlockSpec((1, p.block_k, d),
                      lambda b, i, j, s: (b // group, j, 0)),
@@ -413,30 +451,40 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
     operands = (q, k, v, do, lse, dsum)
     if p.packed:
         q_mult = bh // doc.shape[0]
-        dq_in_specs.append(pl.BlockSpec(
+        in_specs.append(pl.BlockSpec(
             (1, p.block_q, 1), lambda b, i, j, s: (b // q_mult, i, 0)))
         operands = operands + (doc,)
-    dq_grid_spec = pltpu.PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bh, nq, nk),
-        in_specs=dq_in_specs,
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, p.block_q, d),
                                lambda b, i, j, s: (b, i, 0)),
         scratch_shapes=[pltpu.VMEM((p.block_q, d), jnp.float32)],
     )
-    dq = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_dq_kernel, p=p, nk=nk),
-        grid_spec=dq_grid_spec,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(p, d, n_tiles=_BWD_TILES),
         interpret=p.interpret,
         name=spans.FLASH_DQ,
     )(band, *operands)
 
+
+def _bwd_dkv(band, q, k, v, do, lse, dsum, doc=None, *, p: FlashParams):
+    """The dk/dv kernel: grid (B·Hkv, nk, group·nq), q blocks of every
+    query head of the group sequential."""
+    bh, lq, d = q.shape
+    bhkv, lk, _ = k.shape
+    assert bh % bhkv == 0, (bh, bhkv)
+    assert (doc is not None) == p.packed, (doc is None, p.packed)
+    group = bh // bhkv
+    nq = lq // p.block_q
+    nk = lk // p.block_k
     # Query-side operands walk b*group + ig//nq: for a fixed KV head, the
     # sequential dimension visits each group member's q blocks in turn.
-    dkv_in_specs = [
+    in_specs = [
         pl.BlockSpec((1, p.block_q, d),
                      lambda b, j, g, s: (b * group + g // nq,
                                          g % nq, 0)),
@@ -450,16 +498,18 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
         pl.BlockSpec((1, p.block_q, 1),
                      lambda b, j, g, s: (b * group + g // nq, g % nq, 0)),
     ]
+    operands = (q, k, v, do, lse, dsum)
     if p.packed:
         q_mult = bh // doc.shape[0]
-        dkv_in_specs.append(pl.BlockSpec(
+        in_specs.append(pl.BlockSpec(
             (1, p.block_q, 1),
             lambda b, j, g, s: ((b * group + g // nq) // q_mult,
                                 g % nq, 0)))
-    dkv_grid_spec = pltpu.PrefetchScalarGridSpec(
+        operands = operands + (doc,)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bhkv, nk, group * nq),
-        in_specs=dkv_in_specs,
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, p.block_k, d), lambda b, j, g, s: (b, j, 0)),
             pl.BlockSpec((1, p.block_k, d), lambda b, j, g, s: (b, j, 0)),
@@ -469,19 +519,17 @@ def _bwd(q, k, v, out, lse, do, p: FlashParams, band=None, doc=None):
             pltpu.VMEM((p.block_k, d), jnp.float32),
         ],
     )
-    dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_dkv_kernel, p=p, nq=nq, group=group),
-        grid_spec=dkv_grid_spec,
+        grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((bhkv, lk, d), k.dtype),
             jax.ShapeDtypeStruct((bhkv, lk, d), v.dtype),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(p, d, n_tiles=_BWD_TILES),
         interpret=p.interpret,
         name=spans.FLASH_DKV,
     )(band, *operands)
-    return dq, dk, dv
 
 
 # ---------------------------------------------------------------------------

@@ -37,6 +37,12 @@ Masking
 the segmented zigzag layout, letting one kernel call cover any ring-step
 pair (diagonal, j<i, j>i).  Both are honored identically by every impl.
 
+Tiling
+------
+``block_q`` / ``block_k`` left at ``None`` take the tiles
+``choose_blocks`` picks from the lengths (docs/KERNELS.md, "Tiling");
+sizes a caller passes are used as given.
+
 GQA
 ---
 The Pallas forward and dq kernels fold the head group into the K/V index
@@ -50,8 +56,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as ref_mod
-from repro.kernels.flash_attention import (FlashParams, _flash_folded,
-                                           _flash_folded_doc, _fwd, _bwd)
+from repro.kernels.flash_attention import (FlashParams, _bwd,
+                                           _flash_folded, _flash_folded_doc,
+                                           _fwd, _round_up)
 from repro.kernels.ref import BandMask
 
 NEG_INF = ref_mod.NEG_INF
@@ -67,8 +74,32 @@ def resolve_impl(impl: str) -> str:
     return impl
 
 
-def _round_up(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
+#: Cap of the tiles the flash grid takes when the caller passes none, on
+#: both axes (on-chip sweep at B 1, 16 heads, D 128, seq 32768:
+#: docs/KERNELS.md, "Tiling").
+BLOCK_CAP = 1024
+
+
+def choose_blocks(lq: int, lk: int, block_q: int | None = None,
+                  block_k: int | None = None) -> tuple[int, int]:
+    """``(block_q, block_k)`` of the flash grid for lengths ``lq``, ``lk``.
+
+    An explicit size is honoured, cut to the length padded to 8.  Without
+    one, a length under 128 is one tile of ``round_up(L, 8)``; a longer
+    one takes the largest multiple of 128, up to ``BLOCK_CAP``, that
+    divides ``round_up(L, 128)``: it pads no more than 128-tiles would,
+    and the grid has up to ``(BLOCK_CAP / 128)**2`` times fewer steps.
+    """
+    def one(length: int, block: int | None) -> int:
+        if block is not None:
+            return min(block, _round_up(length, 8))
+        if length < 128:
+            return _round_up(length, 8)
+        padded = _round_up(length, 128)
+        return max(t for t in range(128, BLOCK_CAP + 1, 128)
+                   if padded % t == 0)
+
+    return one(lq, block_q), one(lk, block_k)
 
 
 def _fold_pad(x, block_l: int, d_pad: int):
@@ -94,8 +125,7 @@ def _make_params(q, k, *, causal, window, softcap, scale, kv_valid_len,
     _, lk, _, _ = k.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    bq = min(block_q, _round_up(lq, 8))
-    bk = min(block_k, _round_up(lk, 8))
+    bq, bk = choose_blocks(lq, lk, block_q, block_k)
     lk_valid = lk if kv_valid_len is None else kv_valid_len
     return FlashParams(causal=causal, window=window, softcap=float(softcap),
                        scale=float(scale), lq_valid=int(lq),
@@ -146,7 +176,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     kv_valid_len: int | None = None,
                     q_doc_start=None, doc_skip: bool = True,
                     impl: str = "auto",
-                    block_q: int = 128, block_k: int = 128):
+                    block_q: int | None = None,
+                    block_k: int | None = None):
     """Differentiable attention.  Returns out (B, Lq, Hq, D).
 
     ``q_doc_start``: packed-document block-causal masking — a (B, Lq)
@@ -199,7 +230,8 @@ def flash_fwd_chunk(q, k, v, *, causal: bool = False,
                     mask_offset=None, band: BandMask | None = None,
                     q_doc_start=None, doc_skip: bool = True,
                     impl: str = "auto",
-                    block_q: int = 128, block_k: int = 128):
+                    block_q: int | None = None,
+                    block_k: int | None = None):
     """Non-differentiable (out, lse) — ring / decode building block.
 
     out (B, Lq, Hq, D);  lse (B, Hq, Lq) fp32.
@@ -264,7 +296,8 @@ def flash_bwd_chunk(q, k, v, out, lse, do, *, causal: bool = False,
                     mask_offset=None, band: BandMask | None = None,
                     q_doc_start=None, doc_skip: bool = True,
                     impl: str = "auto",
-                    block_q: int = 128, block_k: int = 128):
+                    block_q: int | None = None,
+                    block_k: int | None = None):
     """Chunk backward given global (out, lse).  Returns (dq, dk, dv).
 
     GQA gradients are group-summed inside the dk/dv kernel — no

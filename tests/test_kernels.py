@@ -262,6 +262,83 @@ def test_zigzag_band_all_step_pairs(window, cap):
                                            err_msg=f"bwd i={i} j={j}")
 
 
+# ---------------------------------------------------------------------------
+# Tiles chosen from the shape
+# ---------------------------------------------------------------------------
+
+TILE_CASES = [
+    # lq, lk, block_q, block_k, seg (a zigzag half the tile must divide)
+    (1, 1, None, None, None),
+    (33, 100, None, None, None),            # under 128: round_up(L, 8)
+    (127, 128, None, None, None),
+    (129, 200, None, None, None),           # padded to 256
+    (384, 640, None, None, None),           # 384 | 384; 640 takes 128
+    (1000, 1024, None, None, None),
+    (4096, 4096, None, None, 2048),         # staged grid under hp2
+    (16384, 16384, None, None, 8192),       # staged grid: cp2 local chunk
+    (32768, 32768, None, None, None),       # the one-chip cell
+    (32769, 300, None, None, None),
+    (4096, 4096, 128, 128, None),           # explicit sizes win
+    (64, 48, 32, 16, None),
+    (20, 20, 32, 64, None),                 # explicit, cut to round_up(L, 8)
+    (32768, 32768, 256, None, None),        # one explicit, one chosen
+]
+
+
+@pytest.mark.parametrize("case", TILE_CASES,
+                         ids=[str(i) for i in range(len(TILE_CASES))])
+def test_choose_blocks(case):
+    """The tile rule: explicit sizes as given (cut to the 8-padded length);
+    else under 128 one tile of round_up(L, 8), else the largest multiple
+    of 128 up to the cap that divides round_up(L, 128), so it pads no
+    more than 128-tiles do."""
+    lq, lk, block_q, block_k, seg = case
+    chosen = ops.choose_blocks(lq, lk, block_q, block_k)
+    cap = ops.BLOCK_CAP
+    for length, block, tile in ((lq, block_q, chosen[0]),
+                                (lk, block_k, chosen[1])):
+        if block is not None:
+            assert tile == min(block, -(-length // 8) * 8)
+            continue
+        if length < 128:
+            assert tile == -(-length // 8) * 8
+            continue
+        padded = -(-length // 128) * 128
+        assert tile % 128 == 0 and tile <= cap and padded % tile == 0
+        assert -(-length // tile) * tile == padded     # no extra padding
+        assert not any(padded % t == 0
+                       for t in range(tile + 128, cap + 1, 128))
+        if seg is not None:
+            assert seg % tile == 0
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["causal", "packed"])
+def test_default_tiles_match_oracle(packed):
+    """At L=1536 the rule picks 768-tiles: a 2×2 grid whose block above
+    the diagonal is skipped, and, packed, whose first k block lies wholly
+    before the second q block's first document (doc-skipped).  Fwd and
+    bwd at those default tiles match the oracle."""
+    b, l, h, d = 1, 1536, 2, 128
+    assert ops.choose_blocks(l, l) == (768, 768)
+    q, k, v = t((b, l, h, d)), t((b, l, h, d)), t((b, l, h, d))
+    doc = None
+    if packed:
+        starts = np.repeat([0, 500, 768, 1200], [500, 268, 432, 336])
+        doc = jnp.asarray(starts[None], jnp.int32)
+    o_ref, lse_ref = ref.attention_ref(q, k, v, causal=True, q_doc_start=doc)
+    o_p, lse_p = ops.flash_fwd_chunk(q, k, v, causal=True, q_doc_start=doc,
+                                     impl="pallas_interpret")
+    np.testing.assert_allclose(o_p, o_ref, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(lse_p, lse_ref, atol=2e-5, rtol=2e-5)
+    do = t(o_ref.shape)
+    g_p = ops.flash_bwd_chunk(q, k, v, o_ref, lse_ref, do, causal=True,
+                              q_doc_start=doc, impl="pallas_interpret")
+    g_ref = ref.attention_bwd_ref(q, k, v, o_ref, lse_ref, do, causal=True,
+                                  q_doc_start=doc)
+    for a, b_ in zip(g_p, g_ref):
+        np.testing.assert_allclose(a, b_, atol=1e-4, rtol=1e-4)
+
+
 def test_bwd_gqa_no_expanded_kv():
     """The GQA backward must not allocate group-expanded K/V: no
     intermediate of shape (B*Hq, Lk_pad, D_pad) may appear in the jaxpr."""

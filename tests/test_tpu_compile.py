@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels.ops import flash_attention, flash_bwd_chunk, flash_fwd_chunk
+from repro.kernels.ops import (choose_blocks, flash_attention,
+                               flash_bwd_chunk, flash_fwd_chunk)
 from repro.kernels.ref import BandMask
 from repro.runtime import spans
 
@@ -80,6 +81,20 @@ def test_fwd_bwd_kernel_names(chip):
     compiled = _compile(jax.grad(_loss, argnums=(0, 1, 2)), *_qkv(chip, 1))
     assert set(kernel_names(compiled.as_text()).values()) == {
         spans.FLASH_FWD, spans.FLASH_DQ, spans.FLASH_DKV}
+
+
+@pytest.mark.parametrize("seq,heads,dim", [(32768, 16, 128), (8192, 8, 256)],
+                         ids=["olmo-1b-cell", "head-dim-256"])
+def test_fwd_bwd_default_tiles(chip, seq, heads, dim):
+    """Fwd and bwd at the tiles the rule picks from the length (1024 at
+    these lengths): the one-chip benchmark cell's attention (olmo-1b: B 1,
+    seq 32768, 16 MHA heads, D 128, causal), and a head dim of 256
+    (gemma3's 240, padded), whose kernels pass the default scoped VMEM
+    at these tiles and must ask for more."""
+    assert choose_blocks(seq, seq) == (1024, 1024)
+    q, k, v = (jax.ShapeDtypeStruct((1, seq, heads, dim), jnp.bfloat16,
+                                    sharding=chip) for _ in range(3))
+    _compile(jax.grad(_loss, argnums=(0, 1, 2)), q, k, v)
 
 
 def test_packed_fwd_bwd_batch2(chip):
