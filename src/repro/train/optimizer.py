@@ -52,6 +52,16 @@ def global_norm(tree):
                         for x in jax.tree.leaves(tree)))
 
 
+def decays(path, p) -> bool:
+    """Whether decoupled weight decay applies to the leaf at ``path``:
+    matrices only, by the leaf's rank within one layer.  Leaves under a
+    ``*blocks*`` key are stacked over layers on their leading axis
+    (``models/model.py::init_params``), so a stacked norm gain or bias,
+    ``(L, d)``, is a vector and does not decay."""
+    stacked = any("blocks" in str(getattr(k, "key", "")) for k in path)
+    return p.ndim - stacked >= 2
+
+
 def adamw_update(params, grads, state, cfg: OptConfig):
     """Returns (new_params, new_state, metrics)."""
     step = state["step"] + 1
@@ -62,23 +72,23 @@ def adamw_update(params, grads, state, cfg: OptConfig):
     c1 = 1.0 - b1 ** step.astype(jnp.float32)
     c2 = 1.0 - b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v):
+    def upd(path, p, g, m, v):
         g = g.astype(jnp.float32) * scale
         m = b1 * m + (1 - b1) * g
         v = b2 * v + (1 - b2) * g * g
         mh = m / c1
         vh = v / c2
         step_dir = mh / (jnp.sqrt(vh) + cfg.eps)
-        if p.ndim >= 2:   # decoupled weight decay on matrices only
+        if decays(path, p):
             step_dir = step_dir + cfg.weight_decay * p
         return (p - lr * step_dir).astype(p.dtype), m, v
 
-    flat_p, tdef = jax.tree.flatten(params)
+    flat, tdef = jax.tree_util.tree_flatten_with_path(params)
     flat_g = tdef.flatten_up_to(grads)
     flat_m = tdef.flatten_up_to(state["m"])
     flat_v = tdef.flatten_up_to(state["v"])
-    out = [upd(p, g, m, v) for p, g, m, v in
-           zip(flat_p, flat_g, flat_m, flat_v)]
+    out = [upd(path, p, g, m, v) for (path, p), g, m, v in
+           zip(flat, flat_g, flat_m, flat_v)]
     new_p = tdef.unflatten([o[0] for o in out])
     new_m = tdef.unflatten([o[1] for o in out])
     new_v = tdef.unflatten([o[2] for o in out])

@@ -1,31 +1,21 @@
-"""Distributed equivalence tests — each runs a subprocess with 8 fake host
-devices (device count is locked at first jax import in a process).
+"""Distributed equivalence tests of attention and the model families — each
+runs a subprocess with 8 fake host devices (``_dist_run.py``); the
+training system's checks are in ``test_distributed_train.py``, a file of
+their own so that the two halves run on separate workers.
 
 Marked ``dist`` so the CI fast tier can deselect the whole suite with
 ``-m 'not dist'`` instead of relying on ``-x`` ordering luck.
 """
-import os
-import subprocess
-import sys
-
 import pytest
+
+from _dist_run import run_check
 
 pytestmark = pytest.mark.dist
 
 _CHECKS = ["attention_grid", "attention_modes", "ring_pallas_path", "ssm",
-           "moe", "e2e_loss", "decode_consistency", "grad_compression",
-           "plan_placement", "accum_collectives", "packed_parity",
-           "ckpt_elastic", "offload_parity"]
+           "moe", "e2e_loss", "decode_consistency"]
 
 
 @pytest.mark.parametrize("check", _CHECKS)
 def test_distributed(check):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    script = os.path.join(os.path.dirname(__file__), "_dist_checks.py")
-    res = subprocess.run([sys.executable, script, check],
-                         capture_output=True, text=True, timeout=1200,
-                         env=env)
-    assert res.returncode == 0, f"{check} failed:\n{res.stdout}\n{res.stderr}"
-    assert f"PASS {check}" in res.stdout
+    run_check(check)

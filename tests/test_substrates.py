@@ -159,6 +159,35 @@ def test_grad_clip():
     assert float(m["grad_norm"]) == pytest.approx(50.0, rel=1e-5)
 
 
+@pytest.mark.parametrize("path,decayed", [
+    (("blocks", 0, "ln1", "w"), False),         # stacked (L, d) gain
+    (("blocks", 0, "attn", "qn", "w"), False),  # stacked (L, hd) QK-norm
+    (("final_norm", "w"), False),               # (d,) gain
+    (("blocks", 0, "attn", "wq", "b"), False),  # stacked (L, e) bias
+    (("blocks", 0, "attn", "wq", "w"), True),   # stacked (L, d, e) matrix
+    (("embed", "table"), True),                 # (V, d) matrix
+])
+def test_weight_decay_on_matrices_only(path, decayed):
+    """With a zero gradient the step is the decay alone: a matrix shrinks
+    by ``lr * weight_decay``; a norm gain, stacked over layers or not, is
+    left as it was, and so is a bias."""
+    p = {"embed": {"table": jnp.ones((8, 4))},
+         "final_norm": {"w": jnp.ones((4,))},
+         "blocks": [{"ln1": {"w": jnp.ones((2, 4))},
+                     "attn": {"qn": {"w": jnp.ones((2, 3))},
+                              "wq": {"w": jnp.ones((2, 4, 6)),
+                                     "b": jnp.ones((2, 6))}}}]}
+    cfg = OptConfig(lr=1.0, warmup_steps=0, total_steps=10 ** 6,
+                    weight_decay=0.1)
+    new, _, _ = adamw_update(p, jax.tree.map(jnp.zeros_like, p),
+                             init_opt_state(p), cfg)
+    leaf = new
+    for key in path:
+        leaf = leaf[key]
+    want = 1.0 - 0.1 * float(schedule(cfg, jnp.int32(1))) if decayed else 1.0
+    np.testing.assert_allclose(np.asarray(leaf), want, rtol=1e-6)
+
+
 @settings(max_examples=25, deadline=None)
 @given(seed=st.integers(0, 2 ** 16), steps=st.integers(5, 30))
 def test_int8_error_feedback_unbiased(seed, steps):
