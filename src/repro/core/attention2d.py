@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from repro.core.topology import (AXIS_HP, AXIS_INNER, AXIS_OUTER, BATCH_AXES,
@@ -284,7 +285,12 @@ def ring_attention(q, k, v, doc, cfg: RingConfig):
 
 
 def _ring_vjp_fwd(q, k, v, doc, cfg: RingConfig):
+    # Named for Selective Checkpoint++ (models/model.py::remat_policy): the
+    # backward reads these, so saving them keeps the ring forward out of
+    # the recompute.  ``lse`` (b, h, s) is already lane-dense.
     out, lse = _ring_fwd(q, k, v, doc, cfg)
+    out = checkpoint_name(out, spans.ATTN_OUT)
+    lse = checkpoint_name(lse, spans.ATTN_LSE)
     return out, (q, k, v, doc, out, lse)
 
 

@@ -45,6 +45,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -535,6 +536,19 @@ def _bwd_dkv(band, q, k, v, do, lse, dsum, doc=None, *, p: FlashParams):
 # ---------------------------------------------------------------------------
 # custom_vjp plumbing (head-folded layout)
 # ---------------------------------------------------------------------------
+#
+# The forward rules name the residuals the backward reads (``out`` and
+# ``lse``), so that Selective Checkpoint++ saves them and the backward of a
+# checkpointed layer does not rerun the forward kernel.  ``out`` is the
+# primal output itself: whatever the caller does with it recomputes from
+# the saved copy.  ``lse`` is saved as ``(BH, L)``: a ``(BH, L, 1)`` array
+# pads its size-1 minor axis to 128 lanes in TPU HBM.
+
+def _named_residuals(out, lse):
+    """``(out, lse)`` under their checkpoint names, ``lse`` lane-dense."""
+    return (checkpoint_name(out, spans.ATTN_OUT),
+            checkpoint_name(lse[..., 0], spans.ATTN_LSE))
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _flash_folded(q, k, v, p: FlashParams):
@@ -543,7 +557,7 @@ def _flash_folded(q, k, v, p: FlashParams):
 
 
 def _flash_fwd_rule(q, k, v, p: FlashParams):
-    out, lse = _fwd(q, k, v, p)
+    out, lse = _named_residuals(*_fwd(q, k, v, p))
     return out, (q, k, v, out, lse)
 
 
@@ -551,7 +565,7 @@ def _flash_bwd_rule(p: FlashParams, res, do):
     q, k, v, out, lse = res
     # GQA dk/dv are group-summed inside the dkv kernel (the query group is
     # folded into its sequential grid dimension) — no KV expansion here.
-    return _bwd(q, k, v, out, lse, do, p)
+    return _bwd(q, k, v, out, lse[..., None], do, p)
 
 
 _flash_folded.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -566,13 +580,13 @@ def _flash_folded_doc(q, k, v, doc, p: FlashParams):
 
 
 def _flash_doc_fwd_rule(q, k, v, doc, p: FlashParams):
-    out, lse = _fwd(q, k, v, p, doc=doc)
+    out, lse = _named_residuals(*_fwd(q, k, v, p, doc=doc))
     return out, (q, k, v, doc, out, lse)
 
 
 def _flash_doc_bwd_rule(p: FlashParams, res, do):
     q, k, v, doc, out, lse = res
-    dq, dk, dv = _bwd(q, k, v, out, lse, do, p, doc=doc)
+    dq, dk, dv = _bwd(q, k, v, out, lse[..., None], do, p, doc=doc)
     return dq, dk, dv, np.zeros(doc.shape, jax.dtypes.float0)
 
 

@@ -54,12 +54,14 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.kernels import ref as ref_mod
 from repro.kernels.flash_attention import (FlashParams, _bwd,
                                            _flash_folded, _flash_folded_doc,
                                            _fwd, _round_up)
 from repro.kernels.ref import BandMask
+from repro.runtime import spans
 
 NEG_INF = ref_mod.NEG_INF
 
@@ -190,18 +192,20 @@ def flash_attention(q, k, v, *, causal: bool = False,
     impl = resolve_impl(impl)
     if q_doc_start is not None and not causal:
         raise ValueError("q_doc_start requires causal=True")
+    # The jnp paths' output carries the name the Pallas path's vjp gives
+    # its saved ``out``, so Selective Checkpoint++ saves it on every impl.
     if impl == "flashref":
         out, _ = ref_mod.attention_ref_chunked(
             q, k, v, causal=causal, window=window, softcap=softcap,
             scale=scale, kv_valid_len=kv_valid_len,
             q_doc_start=q_doc_start)
-        return out
+        return checkpoint_name(out, spans.ATTN_OUT)
     if impl == "ref":
         out, _ = ref_mod.attention_ref(
             q, k, v, causal=causal, window=window, softcap=softcap,
             scale=scale, kv_valid_len=kv_valid_len,
             q_doc_start=q_doc_start)
-        return out
+        return checkpoint_name(out, spans.ATTN_OUT)
     interpret = impl == "pallas_interpret"
     b, lq, hq, d = q.shape
     _, lk, hkv, _ = k.shape

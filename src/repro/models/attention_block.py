@@ -11,7 +11,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -87,7 +86,6 @@ def gqa_apply(p, x, cos, sin, rt: Runtime, kind: AttnKind, *,
                            kind, qk_norm=qk_norm)
     cfg = make_2d_cfg(rt, kind, zigzag=zigzag, scale=scale)
     out = attention_2d(q, k, v, mesh=rt.mesh, cfg=cfg, doc_start=doc_start)
-    out = checkpoint_name(out, "attn_out")   # Selective Checkpoint++
     return linear_apply(p["wo"], out.reshape(b, s, n_heads * head_dim))
 
 
@@ -149,7 +147,6 @@ def mla_apply(p, x, cos, sin, rt: Runtime, kind: AttnKind, m: MLADims, *,
                       scale=1.0 / (m.d_qk ** 0.5))
     out = attention_2d(q, k, v_pad, mesh=rt.mesh, cfg=cfg,
                        doc_start=doc_start)[..., :m.d_v]
-    out = checkpoint_name(out, "attn_out")
     return linear_apply(p["wo"], out.reshape(b, s, m.n_heads * m.d_v))
 
 
@@ -199,7 +196,6 @@ def cross_attn_apply(p, x, enc, rt: Runtime, *, n_heads: int,
     spec = P(BATCH_AXES, SEQ_AXES, None, None)
     out = jax.shard_map(local, mesh=rt.mesh, in_specs=(spec, spec, spec),
                         out_specs=spec, check_vma=False)(q, k, v)
-    out = checkpoint_name(out, "attn_out")
     return linear_apply(p["wo"], out.reshape(b, s, n_heads * head_dim))
 
 

@@ -15,7 +15,9 @@ Families:
 Layers are grouped into *periods* (the window/global pattern length) and
 scanned with ``lax.scan`` over stacked params — compile time stays flat in
 depth.  Each scan body is wrapped in ``jax.checkpoint`` with the configured
-policy; Selective Checkpoint++ == ``save_only_these_names("attn_out")``.
+policy; Selective Checkpoint++ saves only the attention residuals the
+backward reads, named where the attention ``custom_vjp`` forward rules make
+them (``spans.ATTN_OUT``, ``spans.ATTN_LSE``).
 
 The cross-entropy is computed in token chunks inside a rematerialized scan so
 the (tokens × vocab) logits never materialize (critical for gemma3's 262k
@@ -135,7 +137,8 @@ def remat_policy(name: str):
     if name == "full":
         return jax.checkpoint_policies.nothing_saveable
     if name == "scpp":
-        return jax.checkpoint_policies.save_only_these_names("attn_out")
+        return jax.checkpoint_policies.save_only_these_names(
+            spans.ATTN_OUT, spans.ATTN_LSE)
     raise ValueError(name)
 
 

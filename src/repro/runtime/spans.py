@@ -18,6 +18,11 @@ other tracing system is involved and nothing is recorded without one.
 
 JAX adds its own entries to an ``op_name``: the backward recomputes what
 the remat policy did not save under ``rematted_computation``.
+
+The checkpoint names (``jax.ad_checkpoint.checkpoint_name``) live here
+too, though no trace shows them: they mark the attention residuals that
+the flash kernel's and the ring's ``custom_vjp`` forward rules make, which
+Selective Checkpoint++ saves (``models/model.py::remat_policy``).
 """
 
 # host spans of Trainer.run
@@ -39,6 +44,10 @@ RING = "ring"               # the Double-Ring passes, kernels included
 FLASH_FWD = "flash_fwd"
 FLASH_DQ = "flash_dq"
 FLASH_DKV = "flash_dkv"
+
+# checkpoint names of the attention residuals the backward reads
+ATTN_OUT = "attn_out"       # attention output, as the vjp keeps it
+ATTN_LSE = "attn_lse"       # fp32 row log-sum-exp, no size-1 minor axis
 
 #: the step's top-level scopes, in the order a by-scope table lists them
 LAYER_SCOPES = (ATTN, MLP, LM_HEAD, OPTIMIZER)

@@ -243,6 +243,66 @@ def check_exchange_scopes():
     print("PASS exchange_scopes")
 
 
+def kernel_calls(jaxpr) -> list:
+    """Names of the ``pallas_call``s in ``jaxpr`` and every jaxpr nested in
+    it (scan, checkpoint, shard_map and custom_vjp bodies), one per call
+    site: a scan body counts once, whatever its length."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for v in eqn.params.values():
+            for u in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(u, "jaxpr", u)
+                if hasattr(inner, "eqns"):
+                    names += kernel_calls(inner)
+    return names
+
+
+def check_scpp_ring_saves_kernel_residuals():
+    """Selective Checkpoint++ on the hp2 x cp2 grid (one inner ring of
+    w = 2): the grad of two checkpointed attention layers holds the ring's
+    w forward kernels once, not again in the backward's recompute (2w under
+    ``full``), and its grads equal those of no remat."""
+    from repro.core.topology import ParallelConfig, make_mesh
+    from repro.core.attention2d import Attn2DConfig, attention_2d
+    from repro.models.model import _scan_blocks, remat_policy
+    from repro.runtime import spans
+
+    pc = ParallelConfig(dp=1, hp=2, cp_outer=1, cp_inner=2)
+    mesh = make_mesh(pc, devices=jax.devices()[:4])
+    cfg = Attn2DConfig(hp=2, n_out=1, w=2, causal=True,
+                       impl="pallas_interpret")
+    B, S, H, D, L = 1, 64, 4, 16, 2
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((B, S, H * D)), jnp.float32)
+    params = {n: jnp.asarray(0.2 * rng.standard_normal((L, H * D, H * D)),
+                             jnp.float32) for n in ("wq", "wk", "wv", "wo")}
+
+    def body(x, lp):
+        q, k, v = ((x @ lp[n]).reshape(B, S, H, D) for n in ("wq", "wk",
+                                                              "wv"))
+        o = attention_2d(q, k, v, mesh=mesh, cfg=cfg)
+        return x + o.reshape(B, S, H * D) @ lp["wo"], jnp.zeros(())
+
+    def grad(remat):
+        def loss(p):
+            return (_scan_blocks(body, x, p, remat_policy(remat))[0]
+                    ** 2).sum()
+        return jax.grad(loss)
+
+    grads = {}
+    with mesh:
+        for remat, n_fwd in (("scpp", cfg.w), ("full", 2 * cfg.w),
+                             ("none", cfg.w)):
+            names = kernel_calls(jax.make_jaxpr(grad(remat))(params).jaxpr)
+            assert names.count(spans.FLASH_FWD) == n_fwd, (remat, names)
+            grads[remat] = grad(remat)(params)
+    for n in params:
+        assert err(grads["scpp"][n], grads["none"][n]) < 1e-5, n
+    print("PASS scpp_ring_saves_kernel_residuals")
+
+
 def check_ssm():
     from repro.core.topology import ParallelConfig
     from repro.models.ssm import (Mamba1Dims, Mamba2Dims, init_mamba1,

@@ -162,3 +162,55 @@ def test_selective_checkpoint_changes_residuals(single_runtime):
         for g1, g2 in zip(jax.tree.leaves(results[a][1]),
                           jax.tree.leaves(results["none"][1])):
             np.testing.assert_allclose(g1, g2, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("packed", [False, True], ids=["flash", "flash_doc"])
+def test_scpp_saves_flash_residuals(packed):
+    """Selective Checkpoint++ saves the flash kernel's ``out`` and ``lse``
+    (named in its ``custom_vjp`` forward rule): the grad of a scan of two
+    checkpointed attention layers holds one ``flash_fwd`` call for the
+    scan body, where ``full`` remat reruns it in the backward, and its
+    grads equal those of no remat."""
+    from _dist_checks import kernel_calls
+    from repro.kernels.ops import flash_attention
+    from repro.models.model import _scan_blocks, remat_policy
+    from repro.runtime import spans
+
+    B, S, H, D, L = 1, 256, 2, 64, 2
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    params = {n: 0.1 * jax.random.normal(k, (L, H * D, H * D))
+              for n, k in zip(("wq", "wk", "wv", "wo"), keys)}
+    x = jax.random.normal(keys[4], (B, S, H * D))
+    doc = (jnp.where(jnp.arange(S) < 100, 0, 100)[None]
+           if packed else None)
+
+    def body(x, lp):
+        q, k, v = ((x @ lp[n]).reshape(B, S, H, D) for n in ("wq", "wk",
+                                                              "wv"))
+        o = flash_attention(q, k, v, causal=True, q_doc_start=doc,
+                            impl="pallas_interpret", block_q=128,
+                            block_k=128)
+        return x + o.reshape(B, S, H * D) @ lp["wo"], jnp.zeros(())
+
+    def grad(remat):
+        def loss(p):
+            return (_scan_blocks(body, x, p, remat_policy(remat))[0]
+                    ** 2).sum()
+        return jax.grad(loss)
+
+    grads = {}
+    for remat, n_fwd in (("scpp", 1), ("full", 2), ("none", 1)):
+        names = kernel_calls(jax.make_jaxpr(grad(remat))(params).jaxpr)
+        assert names.count(spans.FLASH_FWD) == n_fwd, (remat, names)
+        grads[remat] = grad(remat)(params)
+    for n in params:
+        np.testing.assert_allclose(grads["scpp"][n], grads["none"][n],
+                                   rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.dist
+def test_scpp_ring_saves_kernel_residuals():
+    """``tests/_dist_checks.py::check_scpp_ring_saves_kernel_residuals``:
+    the same on the hp2 x cp2 ring, in a process with four devices."""
+    from _dist_run import run_check
+    run_check("scpp_ring_saves_kernel_residuals")

@@ -118,3 +118,46 @@ def test_ring_step_traced_band(chip):
         return out, lse, grads
 
     _compile(step, q, k, v, rank, rank)
+
+
+def test_scpp_saves_fwd_residuals_olmo_cell(chip, capsys):
+    """Selective Checkpoint++ at the one-chip cell's attention (olmo-1b:
+    seq 32768, 16 MHA heads, D 128): the gradient of a checkpointed scan of
+    two layers holds one ``flash_fwd`` kernel, the backward's recompute
+    none, and the saved ``lse`` has no size-1 minor axis, which a TPU would
+    pad to 128 lanes in HBM.  (One layer would not do: XLA drops a loop of
+    one trip and merges the recompute's kernel into the forward's.)"""
+    from bench.trace import kernel_names
+    from repro.models.model import _scan_blocks, remat_policy
+    seq, heads, dim = 32768, 16, 128
+    width = heads * dim
+
+    def body(x, lp):
+        q, k, v = ((x @ lp[n]).reshape(1, seq, heads, dim)
+                   for n in ("wq", "wk", "wv"))
+        o = flash_attention(q, k, v, causal=True, impl="pallas")
+        return x + o.reshape(1, seq, width) @ lp["wo"], jnp.zeros(())
+
+    def loss(x, p):
+        y = _scan_blocks(body, x, p, remat_policy("scpp"))[0]
+        return y.astype(jnp.float32).sum()
+
+    x = jax.ShapeDtypeStruct((1, seq, width), jnp.bfloat16, sharding=chip)
+    params = {n: jax.ShapeDtypeStruct((2, width, width), jnp.bfloat16,
+                                      sharding=chip)
+              for n in ("wq", "wk", "wv", "wo")}
+    compiled = _compile(jax.grad(loss, argnums=1), x, params)
+    kernels = list(kernel_names(compiled.as_text()).values())
+    assert kernels.count(spans.FLASH_FWD) == 1, kernels
+
+    layer = {n: jax.ShapeDtypeStruct((width, width), jnp.bfloat16)
+             for n in params}
+    jax.ad_checkpoint.print_saved_residuals(
+        jax.checkpoint(body, policy=remat_policy("scpp")),
+        jax.ShapeDtypeStruct(x.shape, x.dtype), layer)
+    saved = [r for r in capsys.readouterr().out.splitlines()
+             if "from the argument" not in r]
+    # the kernel's folded out, and lse as (B·H, L)
+    assert [r.split()[0] for r in saved] == [
+        f"bf16[{heads},{seq},{dim}]", f"f32[{heads},{seq}]"], saved
+    assert f"named '{spans.ATTN_LSE}'" in saved[1], saved
