@@ -3,18 +3,13 @@
 #     scripts/check.sh --fast    # tier-1 pytest (single-device tests;
 #                                # dist/slow deselected) + docs check +
 #                                # PlanTuner enumerate+score smoke
-#     scripts/check.sh           # full: all tests + benches + bench gate +
-#                                # plan/tune smoke + serve smoke + packed
-#                                # train smoke + elastic-restart smoke
-# The full tier rewrites BENCH_ring.json / BENCH_train_step.json /
-# BENCH_serve.json / BENCH_tune.json / BENCH_packed.json /
-# BENCH_ckpt.json / BENCH_offload.json and diffs them against the committed
-# baselines (scripts/bench_gate.py) so perf regressions on the ring hot
-# path, the (accumulated) train step, the serving engine, and the tuner's
-# picks show up immediately; the dryrun --plan [--tune] invocations fail
-# fast on ExecutionPlan/PlanTuner regressions for production cells of one
-# arch without compiling anything.  Baselines are refreshed with
-# `python scripts/bench_gate.py --update-baselines` on a quiet machine.
+#     scripts/check.sh           # full: all tests + docs check +
+#                                # elastic-restart smoke + plan/tune
+#                                # smoke + serve smoke + packed train smoke
+# The dryrun --plan [--tune] invocations fail fast on ExecutionPlan/
+# PlanTuner regressions for production cells of one arch without
+# compiling anything.  Speed is measured on the chip by bench/ (see
+# PERF.md), not here.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -30,14 +25,6 @@ fi
 
 python -m pytest -x -q
 python scripts/check_docs.py
-python benchmarks/run.py ring
-python benchmarks/run.py train
-python benchmarks/run.py serve
-python benchmarks/run.py tune
-python benchmarks/run.py packed
-python benchmarks/run.py ckpt
-python benchmarks/run.py offload
-python scripts/bench_gate.py
 python examples/elastic_restart.py
 python -m repro.launch.dryrun --plan --arch qwen3-1.7b --shape all
 python -m repro.launch.dryrun --plan --tune --arch qwen3-1.7b \

@@ -13,13 +13,12 @@ model is re-based on ICI:
   can run concurrently (the "use all NICs" insight).
 
 Consumers: the PlanTuner (``repro/tune``) scores candidate
-``ExecutionPlan``s with it, the roofline (``repro/analysis/roofline.py``)
-shares its hardware constants, and the paper-table benches
-(``benchmarks/run.py`` t2–t5) print it.  The formulas are *models*, cross-checked against dry-run
-collective bytes (see EXPERIMENTS.md §Roofline); the ``CostConstants``
-α factors are calibrated by on-host microbenchmarks
-(``repro/tune/calibrate.py``) and persisted, so predicted step times land
-in the measured ballpark on whatever host runs the tuner.
+``ExecutionPlan``s with it and the roofline (``repro/analysis/roofline.py``)
+shares its hardware constants.  The formulas are *models*, not yet held
+against chip step times; the ``CostConstants`` α factors are calibrated
+by on-host microbenchmarks (``repro/tune/calibrate.py``) and persisted,
+so predicted step times land in the measured ballpark on whatever host
+runs the tuner.
 """
 from __future__ import annotations
 
@@ -71,9 +70,8 @@ def peak_flops(device_kind: str) -> float:
                        f"{device_kind!r}; add it to PEAK_BF16_FLOPS")
     return PEAK_BF16_FLOPS[device_kind]
 
-# Module-level aliases — single source of truth for every consumer that
-# previously duplicated these numbers (analysis/roofline.py and the
-# now-deprecated benchmarks/analytic.py shim).
+# Module-level aliases: the one source of these numbers for every consumer
+# (analysis/roofline.py reads them too).
 PEAK = V5E.peak
 HBM_BW = V5E.hbm
 ICI = V5E.ici

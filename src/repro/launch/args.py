@@ -42,8 +42,6 @@ def add_checkpointing(ap):
                          "auto-resume from the latest step")
     ap.add_argument("--save-every", type=int, default=None,
                     help="async-save cadence in steps (default 50)")
-    ap.add_argument("--ckpt-every", type=int, default=None,
-                    help="deprecated alias of --save-every")
     ap.add_argument("--resume", dest="resume", action="store_true",
                     default=True,
                     help="resume from the latest checkpoint in --ckpt-dir "
@@ -54,13 +52,8 @@ def add_checkpointing(ap):
 
 
 def save_every(args) -> int:
-    """The effective save cadence: ``--save-every`` wins, the deprecated
-    ``--ckpt-every`` alias still works, default 50."""
-    if args.save_every is not None:
-        return args.save_every
-    if args.ckpt_every is not None:
-        return args.ckpt_every
-    return 50
+    """The effective save cadence: ``--save-every``, default 50."""
+    return 50 if args.save_every is None else args.save_every
 
 
 def resolve_tuned(args, cfg, *, seq: int, gb: int, smoke: bool,

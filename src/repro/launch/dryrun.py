@@ -15,9 +15,8 @@ Usage:
     python -m repro.launch.dryrun --arch all --shape all --mesh both
     ... [--hp 8 --cp 2 --inner 1 --placement context_first]
 
-One cell per process is recommended for the full sweep (see
-scripts in EXPERIMENTS.md §Dry-run) — device count is locked at first jax
-use, and cells are independent compiles.
+One cell per process is recommended for the full sweep — device count
+is locked at first jax use, and cells are independent compiles.
 """
 import argparse           # noqa: E402
 import functools          # noqa: E402

@@ -36,11 +36,13 @@ block-causal masking through the 2D-Attention stack; ``--mean-doc-len``
 scales the document-length distribution and the cost model's packing
 term (default ``seq_len // 4``).
 
-``--offload-chunks N`` enables FPDT sequence-chunk pipelining: the plan's
-memory model charges only the HBM-resident chunk fraction (active + next)
-and reports the PCIe wire-time floor plus ``max_seq@budget`` in
-``plan.describe()``.  The PlanTuner proposes a depth automatically when
-the resident plan does not fit the budget.
+``--offload-chunks N`` plans for FPDT sequence-chunk pipelining: the
+plan's memory model charges only the HBM-resident chunk fraction
+(active + next) and reports the PCIe wire-time floor plus
+``max_seq@budget`` in ``plan.describe()``.  The PlanTuner proposes a
+depth automatically when the resident plan does not fit the budget.  The
+train step itself still runs resident: no training path calls
+``chunked_attention_2d`` yet.
 
 PlanTuner integration: ``--plan-file plan.json`` consumes a persisted
 ``TunedPlan`` (no search — the cached winner supplies dp/hp/cp/placement,
@@ -104,10 +106,9 @@ def main():
                          "source's length range and the cost model's "
                          "packing term")
     ap.add_argument("--offload-chunks", type=int, default=None,
-                    help="FPDT sequence-chunk pipelining: stream the "
-                         "sequence through attention in this many chunks "
-                         "with inactive K/V staged in host memory "
-                         "(default: 1 = fully resident, or the tuned "
+                    help="FPDT sequence-chunk depth for the plan's "
+                         "memory model and describe(); the step still "
+                         "runs resident (default: 1, or the tuned "
                          "plan's value under --plan-file)")
     launch_args.add_plan_source(ap)
     launch_args.add_checkpointing(ap)

@@ -70,8 +70,8 @@ def moe_apply(p, x, rt: Runtime, m: MoEDims, seq_sharded: bool = True):
     ``seq_sharded=False`` is the decode path (S=1 cannot shard over sp):
     tokens are replicated across the sp ranks of each data group, so the
     expert compute is duplicated sp-fold — negligible at decode batch
-    sizes, and flagged in EXPERIMENTS.md §Perf as a serving optimization
-    (dispatch from a batch-resharded layout).
+    sizes; dispatching from a batch-resharded layout would remove it (a
+    serving optimization not yet made).
     """
     sizes = _ep_sizes(rt)
     ep = rt.pc.sp

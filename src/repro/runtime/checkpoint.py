@@ -25,8 +25,7 @@ to.  Layout (format 2):  <dir>/step_<N>/
   dp4×cp4 is a reshard at load time, not a migration.
 
 The free functions (``save``/``restore``/``list_steps``/``latest_step``)
-remain as the manager's building blocks; ``AsyncCheckpointer`` is the
-deprecated pre-manager name, kept as an alias.
+remain as the manager's building blocks.
 """
 from __future__ import annotations
 
@@ -200,9 +199,6 @@ class CheckpointManager:
         if w is not None:
             w.join()
 
-    #: pre-manager name for ``flush`` (AsyncCheckpointer API)
-    wait = flush
-
     def _gc(self):
         steps = self.list_steps()
         for s in steps[:-self.keep]:
@@ -232,10 +228,6 @@ class CheckpointManager:
 
     def manifest(self, step: int | None = None) -> dict:
         return read_manifest(self.directory, step)
-
-
-class AsyncCheckpointer(CheckpointManager):
-    """Deprecated pre-manager name; prefer ``CheckpointManager``."""
 
 
 def list_steps(directory: str):

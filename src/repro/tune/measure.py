@@ -3,8 +3,8 @@
 Each candidate is rebuilt as a *real* ExecutionPlan on the attached
 devices (the enumeration/scoring stages never touch device state), its
 train step jitted, and a few steps timed after a warmup.  Measured step
-times re-rank the analytic top-K and land in the ``TunedPlan`` /
-``BENCH_tune.json`` as the predicted-vs-measured record.
+times re-rank the analytic top-K and land in the ``TunedPlan`` as the
+predicted-vs-measured record.
 
 Candidates whose device count exceeds what is attached are skipped with
 a note — measurement is an opt-in refinement, never a requirement

@@ -169,13 +169,14 @@ def test_save_async_rapid_fire_serializes(tmp_path):
     assert mgr.latest_step() == 8
 
 
-def test_save_async_gc_applies_keep(tmp_path):
+@pytest.mark.parametrize("steps", [(1, 2, 3), (1, 2, 3, 4)])
+def test_save_async_gc_applies_keep(tmp_path, steps):
     mgr = ckpt.CheckpointManager(str(tmp_path), keep=2)
     state = {"x": np.zeros(8, np.float32)}
-    for s in (1, 2, 3, 4):
+    for s in steps:
         mgr.save_async(state, s)
-    mgr.wait()                                 # AsyncCheckpointer alias
-    assert mgr.list_steps() == [3, 4]
+    mgr.flush()
+    assert mgr.list_steps() == list(steps[-2:])
 
 
 # ---------------------------------------------------------------------------

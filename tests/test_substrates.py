@@ -104,16 +104,6 @@ def test_checkpoint_atomicity_tmp_invisible():
         assert ckpt.list_steps(d) == [9]
 
 
-def test_async_checkpointer_gc():
-    tree = {"a": jnp.zeros((4,))}
-    with tempfile.TemporaryDirectory() as d:
-        c = ckpt.AsyncCheckpointer(d, keep=2)
-        for s in (1, 2, 3):
-            c.save_async(tree, s)
-        c.wait()
-        assert ckpt.list_steps(d) == [2, 3]
-
-
 def test_elastic_restore_resharding():
     """Save under one sharding, restore under another — the elastic path."""
     pc = ParallelConfig(dp=1)

@@ -234,19 +234,8 @@ def test_attncase_from_plan():
     assert c.cp == 2
 
 
-def test_analytic_shim_deprecated_but_identical():
-    import importlib
-    import warnings
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        import benchmarks.analytic as shim
-        shim = importlib.reload(shim)       # re-fire the import warning
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
-    from repro.analysis import cost
-    assert shim.AttnCase is cost.AttnCase
-    assert shim.attention_op_time is cost.attention_op_time
-    assert shim.PEAK == cost.PEAK and shim.ICI == cost.ICI
-    from repro.analysis import roofline
+def test_roofline_constants_are_the_cost_models():
+    from repro.analysis import cost, roofline
     assert roofline.PEAK_FLOPS == cost.PEAK
     assert roofline.ICI_BW == cost.ICI
 
